@@ -7,12 +7,14 @@ import tempfile
 
 from repro.configs import reduced_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim import AdamWConfig
 from repro.runtime import TrainConfig, Trainer
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = reduced_config("stablelm_3b")
     mesh = make_host_mesh()
     with tempfile.TemporaryDirectory() as tmp:

@@ -16,6 +16,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import ModelConfig
 from repro.optim import AdamWConfig
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--ckpt", default="/tmp/repro_100m")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = model_100m()
     from repro.configs import param_count

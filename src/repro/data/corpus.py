@@ -53,6 +53,10 @@ DECODE_SEQ, DECODE_BATCH = 32_768, 128
 #: whisper limits (encoder frames / decoder positions)
 WHISPER_FRAMES, WHISPER_DECODE = 1500, 448
 
+#: VMEM budget the attention block sizes were derived under when the corpus
+#: was recorded (the kernel itself now asks for less; the goldens stay put)
+CORPUS_VMEM_BUDGET = 64 * 1024 * 1024
+
 
 @dataclass(frozen=True)
 class KernelInstance:
@@ -193,7 +197,7 @@ def extract_profile(inst: KernelInstance) -> Profile:
 def _extract_attention(inst: KernelInstance) -> Profile:
     from repro.kernels.flash_attention import choose_block_sizes
 
-    bq, bkv = choose_block_sizes(inst.seq_q, inst.seq_kv, inst.dh)
+    bq, bkv = choose_block_sizes(inst.seq_q, inst.seq_kv, inst.dh, vmem_budget=CORPUS_VMEM_BUDGET)
     # one thread per q row of the block, floored at two warps
     threads = _clamp(bq, 64, 256)
     q_blocks = _ceil_div(inst.seq_q, bq)
@@ -225,8 +229,8 @@ def _extract_attention(inst: KernelInstance) -> Profile:
 
 def _extract_ssd(inst: KernelInstance) -> Profile:
     P, N, H = inst.ssd_head_dim, inst.ssd_state, inst.ssd_heads
-    # the kernel's own head-block formula (ssd_pallas): largest head block
-    # whose f32 state fits the 8 MiB scratch share, rounded to divide H
+    # the head-block formula ssd_pallas used when the corpus was recorded:
+    # largest head block whose f32 state fits an 8 MiB share, dividing H
     hb = min(H, max(1, (8 * 1024 * 1024) // (P * N * 4)))
     while H % hb:
         hb -= 1

@@ -21,7 +21,8 @@ accumulators carry across kv steps; masking supports causal, sliding-window
 (gemma3) and chunked (llama4) patterns via position arrays.
 
 Validated against :mod:`repro.kernels.ref` in interpret mode (CPU) across
-shape/dtype sweeps; compiled with real BlockSpecs on TPU.
+shape/dtype sweeps; ``tests/test_chip_compile.py`` compiles it for a
+described TPU v5e at stablelm_3b widths.
 """
 
 from __future__ import annotations
@@ -33,11 +34,14 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-#: conservative per-core VMEM budget (bytes) for block-size selection
-VMEM_BUDGET = 64 * 1024 * 1024
+#: scoped VMEM (bytes) the kernels ask the compiler for, and the budget
+#: both size their blocks against.  Unasked, a TPU v5e kernel gets 16 MiB,
+#: which 2048x2048 attention blocks overflow.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 #: MXU tile alignment
 LANE = 128
 SUBLANE = 8
@@ -49,14 +53,15 @@ def _align_up(x: int, unit: int) -> int:
 
 def choose_block_sizes(
     seq_q: int, seq_kv: int, head_dim: int, dtype_bytes: int = 2,
-    vmem_budget: int = VMEM_BUDGET,
+    vmem_budget: int = VMEM_LIMIT_BYTES,
 ) -> Tuple[int, int]:
     """Pick (bq, bkv): largest MXU-aligned blocks fitting the VMEM budget.
 
-    Working set per grid step (all f32 scratch + operand blocks):
-      q (bq, dh) + k (bkv, dh) + v (bkv, dh) + scores (bq, bkv)
-      + acc (bq, dh) + m/l (bq) + out (bq, dh)
-    Doubled for pipelining (double-buffered HBM->VMEM copies).
+    Working set per grid step:
+      operand blocks q (bq, dh), k/v (bkv, dh) and out (bq, dh), each
+      double-buffered for the pipelined HBM<->VMEM copies;
+      f32 values of the body: the q/k/v upcasts, scores and probabilities
+      (bq, bkv) each, and the acc (bq, dh) + m/l (bq) scratch.
 
     Both returned block sizes are always SUBLANE-aligned and never exceed
     the SUBLANE-rounded sequence length; sequences that are not a multiple
@@ -64,9 +69,9 @@ def choose_block_sizes(
     via the position arrays), so any (bq, bkv) this returns is launchable.
     """
     def fits(bq: int, bkv: int) -> bool:
-        operand = (bq * head_dim + 2 * bkv * head_dim) * dtype_bytes
-        scratch = (bq * bkv + 2 * bq * head_dim + 2 * bq) * 4
-        return 2 * operand + scratch <= vmem_budget
+        blocks = 2 * (2 * bq + 2 * bkv) * head_dim * dtype_bytes
+        f32 = ((2 * bq + 2 * bkv) * head_dim + 2 * bq * bkv + 2 * bq) * 4
+        return blocks + f32 <= vmem_budget
 
     # a short sequence gets one SUBLANE-aligned block covering it entirely;
     # longer ones pick from the MXU-friendly ladder (padding covers the
@@ -88,8 +93,8 @@ def _attention_kernel(
     q_ref,      # (1, bq, dh)
     k_ref,      # (1, bkv, dh)
     v_ref,      # (1, bkv, dh)
-    qpos_ref,   # (1, bq)
-    kpos_ref,   # (1, bkv)
+    qpos_ref,   # (1, 1, bq)
+    kpos_ref,   # (1, 1, bkv)
     o_ref,      # (1, bq, dh)
     # VMEM scratch: the demoted accumulators
     m_scr,      # (bq,)
@@ -113,8 +118,8 @@ def _attention_kernel(
     q = q_ref[0].astype(jnp.float32)          # (bq, dh)
     k = k_ref[0].astype(jnp.float32)          # (bkv, dh)
     v = v_ref[0].astype(jnp.float32)
-    qp = qpos_ref[0]                            # (bq,)
-    kp = kpos_ref[0]                            # (bkv,)
+    qp = qpos_ref[0, 0]                         # (bq,)
+    kp = kpos_ref[0, 0]                         # (bkv,)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -203,26 +208,21 @@ def flash_attention_bh(
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bkv, dh), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, bkv, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, bkv), lambda b, i, j: (b, j)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, bkv), lambda b, i, j: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq_p, dh), q.dtype),
         scratch_shapes=[
-            _vmem((bq,), jnp.float32),
-            _vmem((bq,), jnp.float32),
-            _vmem((bq, dh), jnp.float32),
+            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, dh), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
-    )(q, k, v, q_positions, kv_positions)
+    )(q, k, v, q_positions[:, None, :], kv_positions[:, None, :])
     return out[:, :sq] if pad_q else out
 
-
-def _vmem(shape, dtype):
-    """VMEM scratch allocation (TPU); plain scratch under interpret mode."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except (ImportError, AttributeError):  # pragma: no cover
-        return pl.MemorySpace.ANY(shape, dtype)  # type: ignore[attr-defined]

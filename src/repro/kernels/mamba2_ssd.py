@@ -1,25 +1,30 @@
 """Mamba2 SSD chunk-scan Pallas kernel with VMEM-resident recurrent state.
 
-The RegDem adaptation for the SSM family (DESIGN.md §2): the inter-chunk
-recurrent state ``h (heads_blk, P, N)`` is the demoted register — it lives
-in **VMEM scratch** across the chunk-grid dimension instead of being written
-back to HBM between chunks (which is what the pure-JAX ``lax.scan``
-formulation materializes as carry traffic).
+The RegDem adaptation for the SSM family: the inter-chunk recurrent state
+``h (heads_blk, P, N)`` is the demoted register — it lives in **VMEM
+scratch** across the chunk-grid dimension instead of being written back to
+HBM between chunks (which is what the pure-JAX ``lax.scan`` formulation
+materializes as carry traffic).
 
 Grid: (batch, head_blocks, chunks) with chunks innermost.  Per step the
-kernel computes the intra-chunk quadratic dual form and folds the carried
-state, all in fp32 VMEM:
+kernel loops over the heads of its block and, per head, computes the
+intra-chunk quadratic dual form and folds the carried state, all in fp32:
 
     L        = exp(segsum(dt*a))          (Q, Q) lower-triangular decay
     y_intra  = (C B^T . L . dt) x
     y_inter  = C h_prev . decay_from_start
     h       <- h * exp(sum dt*a) + B^T (dt * decay_to_end * x)
 
-Block shapes: Q (chunk length) x P (head dim) x N (state) are already
-MXU-friendly for the assigned configs (Q=256, P=64, N=64/128); the head
-dimension is blocked to keep the working set within the VMEM budget.
+Operands are laid out heads-first, ``(B, H, S, P)`` and ``(B, H, 1, S)``,
+so every per-head value is a 2-D (Q, *) tile: the (Q, Q) intermediates
+exist for one head at a time, and per-position scalars are (1, Q) rows.  A
+row becomes a column, and the chunk-local cumulative sum is taken, by
+masked lane reductions over a (Q, Q) tile (the TPU lowering has no
+``cumsum``); both are exact f32 sums.
 
-Validated against :func:`repro.kernels.ref.ssd_reference` in interpret mode.
+Validated against :func:`repro.kernels.ref.ssd_reference` in interpret mode;
+``tests/test_chip_compile.py`` compiles it for a described TPU v5e at
+mamba2_370m widths.
 """
 
 from __future__ import annotations
@@ -30,66 +35,93 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _vmem
+from .flash_attention import VMEM_LIMIT_BYTES
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32,
+    )
 
 
 def _ssd_kernel(
-    x_ref,    # (1, 1, Q, hb, P)
-    dt_ref,   # (1, 1, Q, hb)
-    a_ref,    # (1, hb)
-    b_ref,    # (1, 1, Q, N)
-    c_ref,    # (1, 1, Q, N)
-    y_ref,    # (1, 1, Q, hb, P)
+    x_ref,    # (1, hb, Q, P)
+    dt_ref,   # (1, hb, 1, Q)
+    da_ref,   # (1, hb, 1, Q)  dt * a
+    b_ref,    # (1, Q, N)
+    c_ref,    # (1, Q, N)
+    y_ref,    # (1, hb, Q, P)
     hlast_ref,  # (1, hb, P, N)
     h_scr,    # VMEM (hb, P, N) — the demoted recurrent state
     *,
     n_chunks: int,
 ):
     ci = pl.program_id(2)
+    hb = h_scr.shape[0]
 
     @pl.when(ci == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0, 0].astype(jnp.float32)    # (Q, hb, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)  # (Q, hb)
-    a = a_ref[0].astype(jnp.float32)       # (hb,)
-    b = b_ref[0, 0].astype(jnp.float32)    # (Q, N)
-    c = c_ref[0, 0].astype(jnp.float32)    # (Q, N)
+    b = b_ref[0].astype(jnp.float32)       # (Q, N)
+    c = c_ref[0].astype(jnp.float32)       # (Q, N)
+    q = b.shape[0]
+    scores = _dot(c, b, ((1,), (1,)))      # (Q, Q), shared by every head
+    row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    lower = row >= col
+    diag = row == col
 
-    da = dt * a[None, :]                   # (Q, hb)
-    da_cum = jnp.cumsum(da, axis=0)        # (Q, hb)
-    da_total = da_cum[-1]                  # (hb,)
+    def head(i, carry):
+        x = x_ref[0, i].astype(jnp.float32)    # (Q, P)
+        dt = dt_ref[0, i].astype(jnp.float32)  # (1, Q)
+        da = da_ref[0, i]                      # (1, Q)
 
-    # ---- intra-chunk quadratic dual form ------------------------------------
-    # L[h, i, j] = exp(da_cum[i,h] - da_cum[j,h]) for i >= j
-    diff = da_cum[:, None, :] - da_cum[None, :, :]       # (Q, Q, hb)
-    q_idx = jax.lax.broadcasted_iota(jnp.int32, diff.shape[:2], 0)
-    k_idx = jax.lax.broadcasted_iota(jnp.int32, diff.shape[:2], 1)
-    tri = (q_idx >= k_idx)[:, :, None]
-    Lm = jnp.where(tri, jnp.exp(diff), 0.0)              # (Q, Q, hb)
-    scores = jax.lax.dot_general(
-        c, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                     # (Q, Q)
-    w = scores[:, :, None] * Lm * dt[None, :, :]          # (Q, Q, hb)
-    y_intra = jnp.einsum("qkh,khp->qhp", w, x)
+        # chunk-local cumulative sum of da, as a column and as a row
+        cum_c = jnp.sum(jnp.where(lower, da, 0.0), axis=1, keepdims=True)     # (Q, 1)
+        cum_r = jnp.sum(jnp.where(diag, cum_c, 0.0), axis=0, keepdims=True)   # (1, Q)
+        total = cum_r[:, q - 1:]                                                # (1, 1)
+        dt_c = jnp.sum(jnp.where(diag, dt, 0.0), axis=1, keepdims=True)       # (Q, 1)
 
-    # ---- inter-chunk from the VMEM-resident state ----------------------------
-    h_prev = h_scr[...]                                   # (hb, P, N)
-    decay_from_start = jnp.exp(da_cum)                    # (Q, hb)
-    y_inter = jnp.einsum("qn,qh,hpn->qhp", c, decay_from_start, h_prev)
+        # ---- intra-chunk quadratic dual form --------------------------------
+        decay = jnp.where(lower, jnp.exp(cum_c - cum_r), 0.0)  # (Q, Q)
+        w = scores * decay * dt
+        y_intra = _dot(w, x, ((1,), (0,)))                     # (Q, P)
 
-    y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
+        # ---- inter-chunk from the VMEM-resident state ------------------------
+        h_prev = h_scr[i]                                      # (P, N)
+        y_inter = _dot(c, h_prev, ((1,), (1,))) * jnp.exp(cum_c)
+        y_ref[0, i] = (y_intra + y_inter).astype(y_ref.dtype)
 
-    # ---- state update ---------------------------------------------------------
-    decay_to_end = jnp.exp(da_total[None, :] - da_cum)    # (Q, hb)
-    new_state = jnp.einsum("qn,qh,qhp->hpn", b, dt * decay_to_end, x)
-    h_scr[...] = h_prev * jnp.exp(da_total)[:, None, None] + new_state
+        # ---- state update ------------------------------------------------------
+        weight = dt_c * jnp.exp(total - cum_c)                 # (Q, 1)
+        h_scr[i] = h_prev * jnp.exp(total) + _dot(x * weight, b, ((0,), (0,)))
+        return carry
+
+    jax.lax.fori_loop(0, hb, head, 0)
 
     @pl.when(ci == n_chunks - 1)
     def _emit():
         hlast_ref[0] = h_scr[...].astype(hlast_ref.dtype)
+
+
+def ssd_vmem_bytes(hb: int, chunk: int, P: int, N: int, itemsize: int) -> int:
+    """VMEM one grid step holds: double-buffered x/y/dt/da/B/C/h_last
+    blocks (a (1, Q) row pads to 8 sublanes), the f32 state scratch, and
+    one head's f32 (Q, Q) and (Q, P) temporaries."""
+    blocks = 2 * (
+        2 * hb * chunk * P * itemsize
+        + 2 * hb * 8 * chunk * 4
+        + 2 * chunk * N * itemsize
+        + hb * P * N * 4
+    )
+    temps = 8 * chunk * chunk * 4 + 4 * chunk * P * 4
+    return blocks + hb * P * N * 4 + temps
 
 
 def ssd_pallas(
@@ -103,43 +135,50 @@ def ssd_pallas(
     head_block: Optional[int] = None,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (y (B,S,H,P), h_last (B,H,P,N))."""
+    """Returns (y (B,S,H,P), h_last (B,H,P,N)).
+
+    ``head_block`` defaults to the largest divisor of H whose step fits
+    :data:`~repro.kernels.flash_attention.VMEM_LIMIT_BYTES`.
+    """
     B, S, H, P = x.shape
     N = bm.shape[-1]
     assert S % chunk == 0, (S, chunk)
     nc = S // chunk
-    hb = head_block or min(H, max(1, (8 * 1024 * 1024) // (P * N * 4)))
-    while H % hb:
-        hb -= 1
-    hblocks = H // hb
+    hb = head_block or max(
+        d for d in range(1, H + 1)
+        if H % d == 0
+        and ssd_vmem_bytes(d, chunk, P, N, x.dtype.itemsize) <= VMEM_LIMIT_BYTES
+    )
+    assert H % hb == 0, (H, hb)
 
-    xc = x.reshape(B, nc, chunk, H, P)
-    dtc = dt.reshape(B, nc, chunk, H)
-    bc = bm.reshape(B, nc, chunk, N)
-    cc = cm.reshape(B, nc, chunk, N)
-    a2 = jnp.broadcast_to(a[None, :], (B, H))
+    xh = x.transpose(0, 2, 1, 3)                                  # (B, H, S, P)
+    dth = dt.transpose(0, 2, 1)[:, :, None, :]                    # (B, H, 1, S)
+    dah = dth.astype(jnp.float32) * a.astype(jnp.float32)[None, :, None, None]
 
     kernel = functools.partial(_ssd_kernel, n_chunks=nc)
-    grid = (B, hblocks, nc)
     y, h_last = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, H // hb, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, chunk, hb, P), lambda b, h, c: (b, c, 0, h, 0)),
-            pl.BlockSpec((1, 1, chunk, hb), lambda b, h, c: (b, c, 0, h)),
-            pl.BlockSpec((1, hb), lambda b, h, c: (b, h)),
-            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, c, 0, 0)),
+            pl.BlockSpec((1, hb, chunk, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, hb, 1, chunk), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((1, hb, 1, chunk), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
+            pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, chunk, hb, P), lambda b, h, c: (b, c, 0, h, 0)),
+            pl.BlockSpec((1, hb, chunk, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, hb, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, nc, chunk, H, P), x.dtype),
+            jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
-        scratch_shapes=[_vmem((hb, P, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, P, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
-    )(xc, dtc, a2, bc, cc)
-    return y.reshape(B, S, H, P), h_last
+    )(xh, dth, dah, bm, cm)
+    return y.transpose(0, 2, 1, 3), h_last

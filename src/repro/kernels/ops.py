@@ -2,10 +2,10 @@
 
 ``flash_attention`` adapts (B, S, H, Dh) model-layout operands (GQA grouping
 included) onto the (batch*heads)-flattened kernel; ``mamba2_ssd`` wraps the
-chunked SSD kernel.  On CPU hosts the wrappers run the kernels in interpret
-mode (the TPU target uses the compiled BlockSpec path); both modes share the
-same kernel body, which is what the shape/dtype sweep tests validate against
-:mod:`repro.kernels.ref`.
+chunked SSD kernel.  With ``interpret=None`` the wrappers run the kernels
+in interpret mode only on the CPU backend; every other backend compiles
+them.  Both modes share the same kernel body, which is what the shape/dtype
+sweep tests validate against :mod:`repro.kernels.ref`.
 """
 
 from __future__ import annotations
@@ -20,8 +20,8 @@ from . import flash_attention as fa
 from . import mamba2_ssd as ssd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret(interpret: Optional[bool]) -> bool:
+    return jax.default_backend() == "cpu" if interpret is None else interpret
 
 
 @functools.partial(
@@ -43,7 +43,7 @@ def flash_attention(
     b, sq, hq, dh = q.shape
     hkv = k.shape[2]
     groups = hq // hkv
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = _interpret(interpret)
 
     # flatten (B, H) and broadcast GQA groups
     qf = q.transpose(0, 2, 1, 3).reshape(b * hq, sq, dh)
@@ -71,7 +71,7 @@ def mamba2_ssd(
     head_block: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = _interpret(interpret)
     return ssd.ssd_pallas(
         x, dt, a, bm, cm, chunk=chunk, head_block=head_block, interpret=interp
     )

@@ -9,6 +9,7 @@ import argparse
 
 from repro.configs import ARCH_IDS, get_config, param_count, reduced_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim import AdamWConfig
 from repro.runtime import TrainConfig, Trainer
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = args.arch.replace("-", "_")
     cfg = reduced_config(arch) if args.smoke else get_config(arch)

@@ -390,15 +390,18 @@ def forward(
     kinds = cfg.layer_kinds()
 
     def scan_body(carry, xs):
-        h = carry
         if kv_caches is not None:
-            lp, kind, ck, cv = xs
-            h, new_kv = block(
+            # the stacked caches ride in the carry and are rewritten one layer
+            # slice at a time, so a donated cache is updated in place
+            h, ck_all, cv_all = carry
+            lp, kind, i = xs
+            h, (ck, cv) = block(
                 cfg, h, lp, kind, positions, attn_impl,
-                kv_cache=(ck, cv), cache_positions=cache_positions,
+                kv_cache=(ck_all[i], cv_all[i]), cache_positions=cache_positions,
                 mrope_positions=mrope_positions,
             )
-            return h, new_kv
+            return (h, ck_all.at[i].set(ck), cv_all.at[i].set(cv)), None
+        h = carry
         lp, kind = xs
         h, _ = block(
             cfg, h, lp, kind, positions, attn_impl,
@@ -417,8 +420,9 @@ def forward(
         )
 
     if kv_caches is not None:
-        xs = (params["layers"], kinds, kv_caches[0], kv_caches[1])
-        h, new_caches = common_scan(body, h, xs)
+        xs = (params["layers"], kinds, jnp.arange(cfg.n_layers))
+        (h, ck, cv), _ = common_scan(body, (h,) + tuple(kv_caches), xs)
+        new_caches = (ck, cv)
     else:
         h, new_caches = common_scan(body, h, (params["layers"], kinds))
 

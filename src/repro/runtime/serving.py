@@ -60,7 +60,17 @@ class Server:
         self.model = Model(model_cfg, attn_impl="chunked")
         self.cfg = cfg
         self.params = params
-        self._decode = jax.jit(self._decode_step)
+        # the batch state is donated to the steps that rewrite it: a cache
+        # held twice (input and output) does not fit beside the weights of a
+        # multi-billion-parameter model on one chip
+        self._decode = jax.jit(self._decode_step, donate_argnums=(2,))
+        self._insert = jax.jit(self._insert_slot, donate_argnums=(0,))
+        # one fused program: eager repeat stages a second copy of each leaf
+        self._replicate = jax.jit(
+            lambda one: self._tree_map_batch(
+                lambda x, ax: jnp.repeat(x, cfg.batch_slots, axis=ax), one
+            )
+        )
         self._prefill = jax.jit(self._prefill_fn)
         # serve-level metrics: always on (one histogram append per finished
         # sequence), same shape as TranslationService.metrics_snapshot
@@ -148,13 +158,9 @@ class Server:
             tok = int(self._sample(logits, rng)[0])
             if state is None:
                 # first fill: broadcast single-slot state into the batch
-                state = self._tree_map_batch(
-                    lambda x, ax: jnp.repeat(x, cfg.batch_slots, axis=ax), st
-                )
+                state = self._replicate(st)
             else:
-                state = self._tree_map_batch2(
-                    lambda full, one, ax: self._set_slot(full, one, slot, ax), state, st
-                )
+                state = self._insert(state, st, jnp.int32(slot))
             slot_tokens[slot].append(tok)
             next_tokens[slot] = tok
 
@@ -213,8 +219,9 @@ class Server:
             lambda path, a, b: fn(a, b, cls._leaf_axis(path)), tree_a, tree_b
         )
 
-    @staticmethod
-    def _set_slot(full: jax.Array, one: jax.Array, slot: int, ax: int) -> jax.Array:
-        idx = [slice(None)] * full.ndim
-        idx[ax] = slice(slot, slot + 1)
-        return full.at[tuple(idx)].set(one)
+    @classmethod
+    def _insert_slot(cls, state: Pytree, one: Pytree, slot: jax.Array) -> Pytree:
+        return cls._tree_map_batch2(
+            lambda full, o, ax: jax.lax.dynamic_update_slice_in_dim(full, o, slot, ax),
+            state, one,
+        )

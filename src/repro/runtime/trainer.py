@@ -154,16 +154,24 @@ class Trainer:
         self._step_fn = step_fn
 
     def init_state(self, rng: Optional[jax.Array] = None) -> Tuple[Pytree, Pytree]:
+        """Parameters and optimizer state, each created in its sharding: no
+        full copy is staged on one device first."""
         rng = rng if rng is not None else jax.random.PRNGKey(self.cfg.seed)
-        params, axes = self.model.init(rng)
-        self._axes = axes
-        shardings = logical_to_sharding(axes, self.mesh, self.rules, like=params)
-        params = jax.device_put(params, shardings)
-        opt_state = adamw_init(params)
+        shapes, self._axes = self.model.abstract_init()
+        shardings = logical_to_sharding(self._axes, self.mesh, self.rules, like=shapes)
+        params = jax.jit(lambda k: self.model.init(k)[0], out_shardings=shardings)(rng)
+        opt_state = jax.jit(adamw_init, out_shardings=self._opt_shardings(shardings))(params)
         return params, opt_state
 
     def param_shardings(self):
         return logical_to_sharding(self._axes, self.mesh, self.rules)
+
+    def _opt_shardings(self, param_shardings):
+        return {
+            "mu": param_shardings,
+            "nu": param_shardings,
+            "count": NamedSharding(self.mesh, P()),
+        }
 
     # -- data ------------------------------------------------------------------
 
@@ -272,11 +280,7 @@ class Trainer:
     def _restore(self, params_like, opt_like):
         shardings = {
             "params": self.param_shardings(),
-            "opt": {
-                "mu": self.param_shardings(),
-                "nu": self.param_shardings(),
-                "count": NamedSharding(self.mesh, P()),
-            },
+            "opt": self._opt_shardings(self.param_shardings()),
         }
         state, extra = self.ckpt.restore(
             {"params": params_like, "opt": opt_like}, shardings=shardings
