@@ -208,20 +208,15 @@ class Server:
         return cls._BATCH_AXIS.get(key, 0)
 
     @classmethod
-    def _tree_map_batch(cls, fn, tree):
+    def _tree_map_batch(cls, fn, *trees):
+        """``fn(*leaves, batch_axis)`` over matching leaves of ``trees``."""
         return jax.tree_util.tree_map_with_path(
-            lambda path, x: fn(x, cls._leaf_axis(path)), tree
-        )
-
-    @classmethod
-    def _tree_map_batch2(cls, fn, tree_a, tree_b):
-        return jax.tree_util.tree_map_with_path(
-            lambda path, a, b: fn(a, b, cls._leaf_axis(path)), tree_a, tree_b
+            lambda path, *xs: fn(*xs, cls._leaf_axis(path)), *trees
         )
 
     @classmethod
     def _insert_slot(cls, state: Pytree, one: Pytree, slot: jax.Array) -> Pytree:
-        return cls._tree_map_batch2(
+        return cls._tree_map_batch(
             lambda full, o, ax: jax.lax.dynamic_update_slice_in_dim(full, o, slot, ax),
             state, one,
         )
