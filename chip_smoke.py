@@ -5,18 +5,14 @@
 
 Weights and data are random, made from ``--seed``.  Each phase prints, on
 its own lines, its wall time, the seconds JAX spent tracing, lowering and
-compiling in it, and the device's peak memory so far in the process.  The
-last line of standard output is one JSON object naming the device.  The
-script exits non-zero, and prints no such line, unless JAX's first device
-is a TPU, or if any check of any phase fails.
+compiling in it, and the device's peak memory so far in the process (the
+counter cannot be reset, so the phases run from the smallest expected peak
+up).  The last line of standard output is one JSON object naming the
+device.  The script exits non-zero, and prints no such line, unless JAX's
+first device is a TPU, or if any check of any phase fails.
 
-Phases (one chip):
+Phases (one chip), in the order they run:
 
-* ``serve`` — stablelm_3b at its published widths and depth through
-  ``Server``: 8 slots, ``max_len`` 2048, 16 prompts of 64 to 1024 tokens,
-  32 new tokens each.  Checks completion, token range and finite logits,
-  and that one request's last decode-step logits through the KV cache
-  agree with a cache-free forward pass (:data:`CACHE_RTOL`).
 * ``kernels`` — ``ops.flash_attention`` at stablelm_3b attention widths
   (prefill 2048, decode against 4096) and ``ops.mamba2_ssd`` at
   mamba2_370m SSD widths, compiled (``interpret=False``, a
@@ -24,6 +20,11 @@ Phases (one chip):
   ``highest`` matmul precision.
 * ``train`` — 3 ``Trainer`` steps of stablelm_3b at published widths cut to
   4 layers, global batch 8 at sequence 1024, on ``make_host_mesh()``.
+* ``serve`` — stablelm_3b at its published widths and depth through
+  ``Server``: 8 slots, ``max_len`` 2048, 16 prompts of 64 to 1024 tokens,
+  32 new tokens each.  Checks completion and token range, and holds every
+  token the Server emitted to the finite logits of a cache-free forward
+  pass over the same tokens (:data:`TOKEN_MARGIN`).
 
 ``--chips 4`` runs only the ``train`` model and batch on a
 (data=1, model=4) mesh and then on a one-device mesh, and checks that the
@@ -61,13 +62,20 @@ from repro.optim import AdamWConfig  # noqa: E402
 from repro.runtime import ServeConfig, Server, TrainConfig, Trainer  # noqa: E402
 from repro.runtime.serving import Request  # noqa: E402
 
-#: Cache path vs cache-free forward, relative L2 error of the last logits.
-#: Both run the same bf16 weights and activations and differ only in
-#: summation order (attention over a 2048-slot cache or over the sequence,
-#: one-row or many-row matmuls), so a bf16 rounding (2**-8 relative) may land
-#: one ulp apart; 2**-5 leaves room for eight such ulps compounding through
-#: the layers.  A wrong cache position or a stale slot gives an O(1) error.
-CACHE_RTOL = 2.0**-5
+#: Server tokens vs a cache-free forward pass, in standard deviations of the
+#: cache-free logit row: how far the logit of the token the Server chose may
+#: trail the row's max.  Sampling is greedy, so the Server's token is the
+#: argmax of logits it computed through its KV cache.  Those and the
+#: cache-free logits run the same bf16 weights and differ only in summation
+#: order (attention over a 2048-slot cache or over the sequence, one-row or
+#: many-row matmuls): a relative L2 error up to 2**-5, room for eight bf16
+#: roundings (2**-8) compounding through the layers.  Over a 50304-entry
+#: row the largest error is about 4.5 times the RMS error, and the chosen
+#: token can trail the max by twice that: 2 * 4.5 * 2**-5 = 0.28.  A token
+#: read from a stale slot, a wrong cache position or a NaN row (argmax then
+#: returns the first NaN's index) is unrelated to the row and trails its
+#: max by about 4.
+TOKEN_MARGIN = 0.3
 #: bf16 flash attention vs the f32 reference: the output is bf16 (2**-9
 #: relative) and the kernel may feed bf16-rounded probabilities to the MXU.
 ATTN_TOL = 2e-2
@@ -165,30 +173,47 @@ def phase_serve(
         _check(len(c.tokens) == new_tokens, f"request {c.uid}: {len(c.tokens)} tokens")
         _check(all(0 <= t < cfg.vocab for t in c.tokens), f"request {c.uid}: token out of vocab")
 
-    # the cache path for the longest request: prefill, then one decode step
-    # per generated token, against one forward pass with no cache
-    req = max(reqs, key=lambda r: len(r.prompt))
-    gen = done[req.uid].tokens
-    prefill = jax.jit(lambda p, t: model.prefill(p, {"tokens": t}, max_len))
-    decode = jax.jit(model.decode_step)
-    h, state = prefill(params, jnp.asarray(req.prompt[None]))
-    for tok in gen[:-1]:
-        h, state = decode(params, jnp.asarray([[tok]], jnp.int32), state)
-    got = np.asarray(model.logits(params, h[:, -1:])[0, 0], np.float32)
-    seq = jnp.asarray(np.concatenate([req.prompt, gen[:-1]])[None].astype(np.int32))
-    nocache = jax.jit(
-        lambda p, t: model.logits(p, transformer.forward(cfg, p, t, attn_impl=model.attn_impl)[0][:, -1:])
-    )
-    want = np.asarray(nocache(params, seq)[0, 0], np.float32)
-    _check(np.isfinite(got).all() and np.isfinite(want).all(), "non-finite logits")
-    rel = float(np.linalg.norm(got - want) / np.linalg.norm(want))
-    print(f"[serve] {len(done)} requests x {new_tokens} tokens; cache vs no-cache logits "
-          f"rel L2 {rel:.3e} (limit {CACHE_RTOL:.3e}), prompt {len(req.prompt)}, "
-          f"argmax equal {int(got.argmax() == want.argmax())}")
-    _check(rel <= CACHE_RTOL, f"cache path logits disagree: rel L2 {rel:.3e}")
+    # every token the Server emitted against a cache-free forward pass over
+    # prompt + tokens[:-1], read at the position that produced it.  With no
+    # EOS every request runs new_tokens steps, so uid 0 fills the batch by
+    # broadcast, uids 1..slots-1 go into fresh slots and every later uid into
+    # a slot that a finished request freed, all through `Server._insert`.
+    seq_len = max(len(r.prompt) for r in reqs) + new_tokens - 1
+
+    @jax.jit
+    def cache_free(p, toks, first):
+        h = transformer.forward(cfg, p, toks, attn_impl=model.attn_impl)[0]
+        at = first[:, None] + jnp.arange(new_tokens)[None]
+        h = jnp.take_along_axis(h, at[..., None], axis=1)
+        return model.logits(p, h).astype(jnp.float32)
+
+    gaps = []
+    for lo in range(0, n_requests, slots):
+        group = reqs[lo:lo + slots]
+        # one shape for every group; the padding sits after each row's last
+        # read position, where causal attention cannot see it
+        toks = np.zeros((slots, seq_len), np.int32)
+        first = np.zeros((slots,), np.int32)
+        for i, r in enumerate(group):
+            toks[i, :len(r.prompt) + new_tokens - 1] = np.concatenate(
+                [r.prompt, done[r.uid].tokens[:-1]]
+            )
+            first[i] = len(r.prompt) - 1
+        want = np.asarray(cache_free(params, toks, first))[:len(group)]
+        _check(np.isfinite(want).all(), "non-finite cache-free logits")
+        for r, w in zip(group, want):
+            got = np.asarray(done[r.uid].tokens)
+            gaps.append((w.max(-1) - w[np.arange(new_tokens), got]) / w.std(-1))
+    gaps = np.stack(gaps)
+    worst = float(gaps.max())
+    print(f"[serve] {len(done)} requests x {new_tokens} tokens; Server tokens vs cache-free "
+          f"logits: {int((gaps == 0).sum())} of {gaps.size} the argmax, worst gap "
+          f"{worst:.3e} std (limit {TOKEN_MARGIN}, request {int(gaps.max(-1).argmax())})")
+    _check(worst <= TOKEN_MARGIN, f"Server tokens disagree with the cache-free pass: {worst:.3e}")
     metrics = server.metrics_snapshot()
-    _free((params, state))
-    return {"requests": len(done), "cache_rel_l2": rel, "tokens": metrics["tokens"]}
+    _free(params)
+    return {"requests": len(done), "tokens": metrics["tokens"], "checked": int(gaps.size),
+            "worst_gap": worst}
 
 
 def _attention_ref(q, k, v, qp, kp):
@@ -341,12 +366,12 @@ def main(argv=None) -> int:
         _check(worst <= LOSS_RTOL, "4-chip and 1-chip losses disagree")
     else:
         one = devices[:1]
-        with _phase("serve", one):
-            phase_serve(get_config("stablelm_3b"), seed=args.seed)
         with _phase("kernels", one):
             phase_kernels(get_config("stablelm_3b"), get_config("mamba2_370m"), seed=args.seed)
         with _phase("train", one):
             phase_train(train_config(), make_host_mesh(devices=one), seed=args.seed)
+        with _phase("serve", one):
+            phase_serve(get_config("stablelm_3b"), seed=args.seed)
 
     print(json.dumps({
         "ok": True,

@@ -65,12 +65,6 @@ class Server:
         # multi-billion-parameter model on one chip
         self._decode = jax.jit(self._decode_step, donate_argnums=(2,))
         self._insert = jax.jit(self._insert_slot, donate_argnums=(0,))
-        # one fused program: eager repeat stages a second copy of each leaf
-        self._replicate = jax.jit(
-            lambda one: self._tree_map_batch(
-                lambda x, ax: jnp.repeat(x, cfg.batch_slots, axis=ax), one
-            )
-        )
         self._prefill = jax.jit(self._prefill_fn)
         # serve-level metrics: always on (one histogram append per finished
         # sequence), same shape as TranslationService.metrics_snapshot
@@ -158,7 +152,9 @@ class Server:
             tok = int(self._sample(logits, rng)[0])
             if state is None:
                 # first fill: broadcast single-slot state into the batch
-                state = self._replicate(st)
+                state = self._tree_map_batch(
+                    lambda x, ax: jnp.repeat(x, cfg.batch_slots, axis=ax), st
+                )
             else:
                 state = self._insert(state, st, jnp.int32(slot))
             slot_tokens[slot].append(tok)

@@ -2,10 +2,12 @@
 anywhere but on a TPU."""
 
 import jax
+import pytest
 
 import chip_smoke
 from repro.configs import reduced_config
 from repro.launch.mesh import make_host_mesh
+from repro.runtime import Server
 
 
 def test_serve_phase_reduced():
@@ -15,7 +17,23 @@ def test_serve_phase_reduced():
     )
     assert out["requests"] == 5
     assert out["tokens"] == 5 * 4
-    assert out["cache_rel_l2"] <= chip_smoke.CACHE_RTOL
+    # every Server token, including those of requests in refilled slots,
+    # held to the cache-free forward pass
+    assert out["checked"] == 5 * 4
+    assert out["worst_gap"] <= chip_smoke.TOKEN_MARGIN
+
+
+def test_serve_phase_catches_a_misplaced_slot(monkeypatch):
+    insert = Server._insert_slot
+    monkeypatch.setattr(
+        Server, "_insert_slot",
+        classmethod(lambda cls, state, one, slot: insert(state, one, (slot + 1) % 2)),
+    )
+    with pytest.raises(chip_smoke.SmokeFailure, match="cache-free"):
+        chip_smoke.phase_serve(
+            reduced_config("stablelm_3b"),
+            slots=2, max_len=64, n_requests=5, new_tokens=4, lengths=(8, 24),
+        )
 
 
 def test_kernels_phase_reduced():
