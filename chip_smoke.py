@@ -175,9 +175,9 @@ def phase_serve(
 
     # every token the Server emitted against a cache-free forward pass over
     # prompt + tokens[:-1], read at the position that produced it.  With no
-    # EOS every request runs new_tokens steps, so uid 0 fills the batch by
-    # broadcast, uids 1..slots-1 go into fresh slots and every later uid into
-    # a slot that a finished request freed, all through `Server._insert`.
+    # EOS every request runs new_tokens steps, so uids 0..slots-1 go into the
+    # empty batch and every later uid into a slot that a finished request
+    # freed, all through `Server._insert`.
     seq_len = max(len(r.prompt) for r in reqs) + new_tokens - 1
 
     @jax.jit

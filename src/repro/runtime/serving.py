@@ -151,12 +151,16 @@ class Server:
             logits, st = self._prefill(self.params, jnp.asarray(prompt))
             tok = int(self._sample(logits, rng)[0])
             if state is None:
-                # first fill: broadcast single-slot state into the batch
+                # first fill: an empty batch, filled like every later slot.
+                # An eager jnp.repeat would stage a broadcast copy of each
+                # cache leaf beside its result.
                 state = self._tree_map_batch(
-                    lambda x, ax: jnp.repeat(x, cfg.batch_slots, axis=ax), st
+                    lambda x, ax: jnp.zeros(
+                        x.shape[:ax] + (cfg.batch_slots,) + x.shape[ax + 1:], x.dtype
+                    ),
+                    st,
                 )
-            else:
-                state = self._insert(state, st, jnp.int32(slot))
+            state = self._insert(state, st, jnp.int32(slot))
             slot_tokens[slot].append(tok)
             next_tokens[slot] = tok
 
