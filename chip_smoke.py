@@ -210,9 +210,9 @@ def phase_serve(
           f"logits: {int((gaps == 0).sum())} of {gaps.size} the argmax, worst gap "
           f"{worst:.3e} std (limit {TOKEN_MARGIN}, request {int(gaps.max(-1).argmax())})")
     _check(worst <= TOKEN_MARGIN, f"Server tokens disagree with the cache-free pass: {worst:.3e}")
-    metrics = server.metrics_snapshot()
     _free(params)
-    return {"requests": len(done), "tokens": metrics["tokens"], "checked": int(gaps.size),
+    return {"requests": len(done), "tokens": sum(len(c.tokens) for c in done),
+            "checked": int(gaps.size),
             "worst_gap": worst}
 
 

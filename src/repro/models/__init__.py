@@ -24,7 +24,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from . import encdec, hybrid, mamba2, transformer
+from . import encdec, hybrid, mamba2, scopes, transformer
 from .common import scan as common_scan
 from .transformer import BIG, ModelConfig, MoEConfig
 
@@ -260,6 +260,7 @@ class Model:
 
     def logits(self, params: Pytree, h: jax.Array) -> jax.Array:
         cfg = self.cfg
-        if cfg.family in ("dense", "moe", "vlm") and not cfg.tie_embeddings:
-            return transformer.lm_head(cfg, params, h)
-        return h @ params["embed"].T.astype(h.dtype)
+        with jax.named_scope(scopes.LM_HEAD):
+            if cfg.family in ("dense", "moe", "vlm") and not cfg.tie_embeddings:
+                return transformer.lm_head(cfg, params, h)
+            return h @ params["embed"].T.astype(h.dtype)

@@ -21,6 +21,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import scopes
 from .common import scan as common_scan, rms_norm, trunc_normal
 
 Pytree = Any
@@ -226,14 +227,16 @@ def mamba_layer(
 
     if decode:
         assert ssm_state is not None
-        y, new_state = ssd_decode_step(
-            xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], ssm_state
-        )
+        with jax.named_scope(scopes.SSD):
+            y, new_state = ssd_decode_step(
+                xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], ssm_state
+            )
         y = y[:, None]  # (B, 1, H, P)
     else:
         bm4 = bm.reshape(B, -1, N_GROUPS, N)
         cm4 = cm.reshape(B, -1, N_GROUPS, N)
-        y, new_state = ssd_chunked(xs, dt, a, bm4, cm4, chunk=chunk, h0=ssm_state)
+        with jax.named_scope(scopes.SSD):
+            y, new_state = ssd_chunked(xs, dt, a, bm4, cm4, chunk=chunk, h0=ssm_state)
 
     y = y + xs.astype(jnp.float32) * lp["d_skip"][None, None, :, None]
     y = y.reshape(B, -1, d_inner).astype(h.dtype)

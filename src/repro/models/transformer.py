@@ -26,6 +26,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import scopes
 from .attention import attention
 from .common import scan as common_scan, apply_mrope, apply_rope, rms_norm, swiglu, trunc_normal
 
@@ -324,17 +325,19 @@ def block(
     mrope_positions: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
     """One pre-norm transformer block; returns (h, new_kv)."""
-    x = rms_norm(h, lp["ln1"])
-    q, k, v = _qkv(x, lp, cfg)
-    q = _rope(cfg, q, positions, kind, mrope_positions)
-    k = _rope(cfg, k, positions, kind, mrope_positions)
+    with jax.named_scope(scopes.QKV):
+        x = rms_norm(h, lp["ln1"])
+        q, k, v = _qkv(x, lp, cfg)
+        q = _rope(cfg, q, positions, kind, mrope_positions)
+        k = _rope(cfg, k, positions, kind, mrope_positions)
 
     if kv_cache is not None:
         ck, cv = kv_cache  # (B, Skv, Hkv, Dh)
         # decode: insert current token(s) at their positions
-        upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice(c, u, (p, 0, 0)))
-        ck = upd(ck, k.astype(ck.dtype), positions[:, 0])
-        cv = upd(cv, v.astype(cv.dtype), positions[:, 0])
+        with jax.named_scope(scopes.KV_WRITE):
+            upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice(c, u, (p, 0, 0)))
+            ck = upd(ck, k.astype(ck.dtype), positions[:, 0])
+            cv = upd(cv, v.astype(cv.dtype), positions[:, 0])
         k_att, v_att = ck, cv
         kv_positions = cache_positions
         new_cache = (ck, cv)
@@ -344,19 +347,21 @@ def block(
         new_cache = None
 
     window, chunk = _mask_params(cfg, kind)
-    o = attention(
-        q, k_att, v_att, positions, kv_positions,
-        impl=attn_impl, window=window, chunk_attn=chunk,
-    )
-    B, S = h.shape[:2]
-    h = h + (o.reshape(B, S, -1) @ lp["wo"]).astype(h.dtype)
+    with jax.named_scope(scopes.ATTENTION):
+        o = attention(
+            q, k_att, v_att, positions, kv_positions,
+            impl=attn_impl, window=window, chunk_attn=chunk,
+        )
+        B, S = h.shape[:2]
+        h = h + (o.reshape(B, S, -1) @ lp["wo"]).astype(h.dtype)
 
-    x = rms_norm(h, lp["ln2"])
-    if cfg.moe is None:
-        y = swiglu(x @ lp["w_gate"], x @ lp["w_up"]) @ lp["w_down"]
-    else:
-        y = moe_ffn(x.reshape(-1, cfg.d_model), lp, cfg.moe).reshape(x.shape)
-    h = h + y.astype(h.dtype)
+    with jax.named_scope(scopes.MLP):
+        x = rms_norm(h, lp["ln2"])
+        if cfg.moe is None:
+            y = swiglu(x @ lp["w_gate"], x @ lp["w_up"]) @ lp["w_down"]
+        else:
+            y = moe_ffn(x.reshape(-1, cfg.d_model), lp, cfg.moe).reshape(x.shape)
+        h = h + y.astype(h.dtype)
     return h, new_cache
 
 
@@ -395,12 +400,16 @@ def forward(
             # slice at a time, so a donated cache is updated in place
             h, ck_all, cv_all = carry
             lp, kind, i = xs
+            with jax.named_scope(scopes.KV_CARRY):
+                layer_kv = (ck_all[i], cv_all[i])
             h, (ck, cv) = block(
                 cfg, h, lp, kind, positions, attn_impl,
-                kv_cache=(ck_all[i], cv_all[i]), cache_positions=cache_positions,
+                kv_cache=layer_kv, cache_positions=cache_positions,
                 mrope_positions=mrope_positions,
             )
-            return (h, ck_all.at[i].set(ck), cv_all.at[i].set(cv)), None
+            with jax.named_scope(scopes.KV_CARRY):
+                ck_all, cv_all = ck_all.at[i].set(ck), cv_all.at[i].set(cv)
+            return (h, ck_all, cv_all), None
         h = carry
         lp, kind = xs
         h, _ = block(

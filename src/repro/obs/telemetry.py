@@ -19,6 +19,12 @@ constraints, in order:
    ``pid``, so spans captured in search-pool workers merge into the parent
    timeline exactly like :meth:`repro.core.simcache.SimCache.export` /
    ``merge`` payloads do.
+4. **One timeline with the device.**  While enabled, every span also holds
+   a ``jax.profiler.TraceAnnotation`` carrying its name and attributes, so
+   a profiler trace (``jax.profiler.trace(dir)``) shows the program's spans
+   on the same clock as the device's operations.  JAX is never imported
+   here: a process that has not imported it cannot be running its
+   profiler, and its spans open no annotation.
 
 Exporters live in :mod:`repro.obs.export` (JSONL event log, Chrome
 trace-format for ``chrome://tracing`` / Perfetto).
@@ -27,6 +33,7 @@ trace-format for ``chrome://tracing`` / Perfetto).
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -83,20 +90,32 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _trace_annotation(name: str, attrs: Dict[str, object]):
+    """A JAX profiler annotation for a live span, or None where the process
+    has not imported JAX."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
 class Span:
     """One live span; records itself on ``__exit__`` (exceptions included)."""
 
-    __slots__ = ("_tel", "name", "attrs", "_t0", "span_id", "parent_id")
+    __slots__ = ("_tel", "name", "attrs", "_t0", "span_id", "parent_id", "_annotation")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: Dict[str, object]):
         self._tel = tel
         self.name = name
         self.attrs = attrs
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes to the live span (e.g. an outcome computed
         mid-flight)."""
         self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "Span":
@@ -105,11 +124,16 @@ class Span:
         stack = tel._stack()
         self.parent_id = stack[-1] if stack else None
         stack.append(self.span_id)
+        self._annotation = _trace_annotation(self.name, self.attrs)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         tel = self._tel
         stack = tel._stack()
         # pop back to this span even if an inner span leaked (belt and

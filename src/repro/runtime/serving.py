@@ -24,7 +24,6 @@ import numpy as np
 
 from repro import obs
 from repro.models import Model, ModelConfig
-from repro.obs import Histogram
 
 Pytree = Any
 
@@ -66,23 +65,6 @@ class Server:
         self._decode = jax.jit(self._decode_step, donate_argnums=(2,))
         self._insert = jax.jit(self._insert_slot, donate_argnums=(0,))
         self._prefill = jax.jit(self._prefill_fn)
-        # serve-level metrics: always on (one histogram append per finished
-        # sequence), same shape as TranslationService.metrics_snapshot
-        self._latency_ms = Histogram()
-        self._tokens_done = 0
-        self._busy_seconds = 0.0
-
-    def metrics_snapshot(self) -> dict:
-        """Serving health as one plain dict: completion latency distribution
-        (p50/p99) and lifetime decode throughput."""
-        return {
-            "completions": self._latency_ms.count,
-            "tokens": self._tokens_done,
-            "tokens_per_s": round(
-                self._tokens_done / self._busy_seconds, 3
-            ) if self._busy_seconds else 0.0,
-            "latency_ms": self._latency_ms.snapshot(),
-        }
 
     # -- jitted steps -----------------------------------------------------------
 
@@ -107,22 +89,19 @@ class Server:
     # -- the serving loop ----------------------------------------------------------
 
     def serve(self, requests: List[Request]) -> List[Completion]:
-        t_call = time.perf_counter()
         with obs.span("serve", requests=len(requests)) as sp:
             done = self._serve(requests)
             sp.set(completions=len(done))
-        seconds = time.perf_counter() - t_call
-        self._busy_seconds += seconds
-        for c in done:
-            self._latency_ms.observe(c.latency_s * 1e3)
-            self._tokens_done += len(c.tokens)
-        if obs.enabled():
-            reg = obs.metrics()
-            reg.counter("serve.completions").inc(len(done))
-            reg.histogram("serve.batch_s").observe(seconds)
         return done
 
     def _serve(self, requests: List[Request]) -> List[Completion]:
+        """The serving loop.  With :mod:`repro.obs` enabled it records, under
+        the ``serve`` span: a ``serve.fill`` per request put into a slot
+        (``uid``, ``slot``, ``prompt_len``), holding ``serve.prefill``,
+        ``serve.sample`` and ``serve.insert``; and a ``serve.step`` per
+        decode step (``step``, ``active`` slots, ``refills`` made after it),
+        holding ``serve.decode``, ``serve.sample`` (where the host waits for
+        the device's result) and the step's refills."""
         cfg = self.cfg
         rng = np.random.default_rng(cfg.seed)
         pending = queue.SimpleQueue()
@@ -138,55 +117,69 @@ class Server:
         state = None
         next_tokens = np.zeros((cfg.batch_slots,), np.int32)
 
-        def fill_slot(slot: int):
+        def fill_slot(slot: int) -> bool:
+            """Put the next pending request into ``slot``; False if none."""
             nonlocal state, next_tokens
             if pending.empty():
                 slot_req[slot] = None
-                return
+                return False
             req = pending.get()
             slot_req[slot] = req
             slot_tokens[slot] = []
             slot_start[slot] = time.perf_counter()
             prompt = req.prompt[None, :]  # (1, L)
-            logits, st = self._prefill(self.params, jnp.asarray(prompt))
-            tok = int(self._sample(logits, rng)[0])
-            if state is None:
-                # first fill: an empty batch, filled like every later slot.
-                # An eager jnp.repeat would stage a broadcast copy of each
-                # cache leaf beside its result.
-                state = self._tree_map_batch(
-                    lambda x, ax: jnp.zeros(
-                        x.shape[:ax] + (cfg.batch_slots,) + x.shape[ax + 1:], x.dtype
-                    ),
-                    st,
-                )
-            state = self._insert(state, st, jnp.int32(slot))
+            with obs.span("serve.fill", uid=req.uid, slot=slot, prompt_len=prompt.shape[1]):
+                with obs.span("serve.prefill"):
+                    logits, st = self._prefill(self.params, jnp.asarray(prompt))
+                with obs.span("serve.sample"):
+                    tok = int(self._sample(logits, rng)[0])
+                if state is None:
+                    # first fill: an empty batch, filled like every later slot.
+                    # An eager jnp.repeat would stage a broadcast copy of each
+                    # cache leaf beside its result.
+                    state = self._tree_map_batch(
+                        lambda x, ax: jnp.zeros(
+                            x.shape[:ax] + (cfg.batch_slots,) + x.shape[ax + 1:], x.dtype
+                        ),
+                        st,
+                    )
+                with obs.span("serve.insert"):
+                    state = self._insert(state, st, jnp.int32(slot))
             slot_tokens[slot].append(tok)
             next_tokens[slot] = tok
+            return True
 
         for slot in range(cfg.batch_slots):
             fill_slot(slot)
 
+        step = 0
         while any(r is not None for r in slot_req):
-            logits, state = self._decode(
-                self.params, jnp.asarray(next_tokens)[:, None], state
-            )
-            sampled = self._sample(logits, rng)
-            for slot, req in enumerate(slot_req):
-                if req is None:
-                    continue
-                tok = int(sampled[slot])
-                slot_tokens[slot].append(tok)
-                next_tokens[slot] = tok
-                if tok == cfg.eos or len(slot_tokens[slot]) >= cfg.max_new_tokens:
-                    done.append(
-                        Completion(
-                            uid=req.uid,
-                            tokens=list(slot_tokens[slot]),
-                            latency_s=time.perf_counter() - slot_start[slot],
-                        )
+            active = sum(r is not None for r in slot_req)
+            with obs.span("serve.step", step=step, active=active) as step_span:
+                with obs.span("serve.decode"):
+                    logits, state = self._decode(
+                        self.params, jnp.asarray(next_tokens)[:, None], state
                     )
-                    fill_slot(slot)
+                with obs.span("serve.sample"):
+                    sampled = self._sample(logits, rng)
+                refills = 0
+                for slot, req in enumerate(slot_req):
+                    if req is None:
+                        continue
+                    tok = int(sampled[slot])
+                    slot_tokens[slot].append(tok)
+                    next_tokens[slot] = tok
+                    if tok == cfg.eos or len(slot_tokens[slot]) >= cfg.max_new_tokens:
+                        done.append(
+                            Completion(
+                                uid=req.uid,
+                                tokens=list(slot_tokens[slot]),
+                                latency_s=time.perf_counter() - slot_start[slot],
+                            )
+                        )
+                        refills += fill_slot(slot)
+                step_span.set(refills=refills)
+            step += 1
         return sorted(done, key=lambda c: c.uid)
 
     # -- slot surgery -------------------------------------------------------------

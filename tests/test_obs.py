@@ -127,6 +127,83 @@ def test_reset_drops_events_but_not_the_switch(tel):
 
 
 # ---------------------------------------------------------------------------
+# spans on the JAX profiler's timeline
+# ---------------------------------------------------------------------------
+
+
+class _Annotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``; keeps what a span
+    asked of it."""
+
+    opened = []
+
+    def __init__(self, name, **attrs):
+        self.name, self.attrs, self.state = name, dict(attrs), "made"
+        _Annotation.opened.append(self)
+
+    def __enter__(self):
+        self.state = "open"
+        return self
+
+    def __exit__(self, *exc):
+        self.state = "closed"
+
+    def set_metadata(self, **attrs):
+        self.attrs.update(attrs)
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax
+
+    _Annotation.opened = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    return _Annotation.opened
+
+
+def test_enabled_span_opens_a_profiler_annotation_with_its_attributes(tel, annotations):
+    with obs.span("serve.step", step=3, active=2) as sp:
+        assert [(a.name, a.attrs, a.state) for a in annotations] == [
+            ("serve.step", {"step": 3, "active": 2}, "open")]
+        sp.set(refills=1)
+    (ann,) = annotations
+    assert ann.state == "closed" and ann.attrs == {"step": 3, "active": 2, "refills": 1}
+    assert tel.events[-1].attrs == ann.attrs
+
+
+def test_disabled_span_opens_no_profiler_annotation(tel, annotations):
+    tel.disable()
+    with obs.span("serve.step", step=3) as sp:
+        sp.set(refills=1)
+    assert sp is NULL_SPAN and annotations == [] and tel.events == []
+
+
+def test_span_without_jax_imported_opens_no_annotation(tel, annotations, monkeypatch):
+    import sys
+
+    monkeypatch.delitem(sys.modules, "jax")
+    with obs.span("translate", kernel="nn"):
+        pass
+    assert annotations == [] and [e.name for e in tel.events] == ["translate"]
+
+
+def test_enabled_spans_land_in_a_profiler_trace(tel, tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("serve.step", step=7, active=2) as sp:
+            with obs.span("serve.decode"):
+                jax.numpy.ones(4).block_until_ready()
+            sp.set(refills=0)
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    found = {ev.name: dict(ev.stats) for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events if ev.name.startswith("serve.")}
+    assert found["serve.step"] == {"step": 7, "active": 2, "refills": 0}
+    assert "serve.decode" in found
+
+
+# ---------------------------------------------------------------------------
 # metrics
 # ---------------------------------------------------------------------------
 

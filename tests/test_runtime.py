@@ -182,3 +182,64 @@ def test_server_continuous_batching():
     assert [c.uid for c in done] == [0, 1, 2, 3, 4]
     for c in done:
         assert 1 <= len(c.tokens) <= 4
+
+
+@pytest.fixture
+def telemetry():
+    """The process-wide telemetry, clean and enabled; restored afterwards."""
+    from repro import obs
+
+    t = obs.get_telemetry()
+    was_enabled, saved = t.enabled, t.export_events(0)
+    t.reset()
+    t.enable()
+    yield t
+    t.reset()
+    t.adopt(saved)
+    t.enabled = was_enabled
+
+
+def test_server_spans_each_decode_step_and_fill(telemetry):
+    cfg = reduced_config("stablelm_3b")
+    params, _ = Model(cfg).init(jax.random.PRNGKey(0))
+    scfg = ServeConfig(batch_slots=2, max_len=32, max_new_tokens=4, eos=-1)
+    reqs = [Request(uid=i, prompt=np.arange(1, 5 + i, dtype=np.int32)) for i in range(5)]
+
+    telemetry.disable()
+    off = Server(cfg, scfg, params).serve(reqs)
+    assert telemetry.events == []
+
+    telemetry.enable()
+    server = Server(cfg, scfg, params)
+    decode, decode_calls = server._decode, []
+    server._decode = lambda *a: decode_calls.append(1) or decode(*a)
+    on = server.serve(reqs)
+    assert [(c.uid, c.tokens) for c in on] == [(c.uid, c.tokens) for c in off]
+
+    events = telemetry.events
+    by_id = {e.span_id: e for e in events}
+    parent = lambda e: by_id[e.parent_id].name  # noqa: E731
+    (serve,) = [e for e in events if e.name == "serve"]
+    steps = [e for e in events if e.name == "serve.step"]
+    fills = [e for e in events if e.name == "serve.fill"]
+    assert len(steps) == len(decode_calls) > 0
+    assert [e.attrs["step"] for e in steps] == list(range(len(steps)))
+    assert all(1 <= e.attrs["active"] <= 2 for e in steps)
+    # two fills into the empty batch, one refill for every later request
+    assert sorted(e.attrs["uid"] for e in fills) == [0, 1, 2, 3, 4]
+    assert {e.attrs["prompt_len"] for e in fills} == {len(r.prompt) for r in reqs}
+    assert {e.attrs["slot"] for e in fills} == {0, 1}
+    assert sum(e.attrs["refills"] for e in steps) == 3
+    assert [parent(e) for e in fills] == ["serve"] * 2 + ["serve.step"] * 3
+    assert all(e.parent_id == serve.span_id for e in steps)
+    for name, parents in (("serve.decode", {"serve.step"}), ("serve.prefill", {"serve.fill"}),
+                          ("serve.insert", {"serve.fill"}),
+                          ("serve.sample", {"serve.step", "serve.fill"})):
+        spans = [e for e in events if e.name == name]
+        assert spans and {parent(e) for e in spans} == parents
+    assert sum(1 for e in events if e.name == "serve.decode") == len(steps)
+    assert sum(1 for e in events if e.name == "serve.prefill") == len(fills)
+    # each refill falls inside the step it follows
+    for e in fills[2:]:
+        step = by_id[e.parent_id]
+        assert step.ts <= e.ts and e.ts + e.dur <= step.ts + step.dur
