@@ -8,10 +8,15 @@ compiled HLO text.  A device trace names each operation after its
 instruction (``%fusion.12 = bf16[...] fusion(...), ...``).
 
 :func:`instruction_scopes` maps every instruction of a compiled text to the
-innermost scope its ``op_name`` names.  A fusion whose own metadata names
-none takes the scope most of the instructions fused into it carry.  An
-instruction in no scope is ``unscoped``: scan machinery, the embedding
-gather, the final norm, and the copies the compiler inserts.
+innermost scope its ``op_name`` names.  A fusion with no ``op_name`` of its
+own takes the scope its result is computed in: the scope of the fused
+instructions that feed its root, where they all name one.  An instruction
+in no scope is ``unscoped``: scan machinery, the embedding gather, the
+final norm, and the copies and selects the compiler adds without scope
+metadata.  In the serving cells' decode step those include the token
+write's whole-slice select and the slice's layout copies into the write's
+scatter loop and into attention's chunk loop (``PERF.md`` section 5): the
+price of ``kv_write`` and ``attention`` that reads in ``unscoped``.
 
 :func:`split` sums one program's operation time per scope.  Where
 operations nest (a loop around its body), each instant counts once, for the
@@ -41,7 +46,8 @@ DECODE_PROGRAM = "_decode_step"
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+) = (.*)$")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s.*\{\s*$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
-_CALLS = re.compile(r"\bcalls=(%?[^\s,]+(?:,\s*%[^\s,]+)*)")
+_CALLS = re.compile(r"\bcalls=%?([^\s,]+)")
+_OPERAND = re.compile(r"(?<![=\w])%([\w.\-]+)")  # not a called computation
 
 
 def _innermost(op_name: str, names: Sequence[str]) -> Optional[str]:
@@ -54,10 +60,12 @@ def _innermost(op_name: str, names: Sequence[str]) -> Optional[str]:
 
 def instruction_scopes(text: str, names: Sequence[str]) -> Dict[str, str]:
     """Instruction name -> the innermost of ``names`` in its ``op_name``
-    (fusions: see the module's docstring), or ``unscoped``."""
+    (fusions without one: see the module's docstring), or ``unscoped``."""
     own: Dict[str, Optional[str]] = {}
-    calls: Dict[str, List[str]] = {}
-    members: Dict[str, List[str]] = collections.defaultdict(list)
+    marked = set()                       # instructions with an op_name
+    operands: Dict[str, List[str]] = {}
+    roots: Dict[str, str] = {}           # computation -> its ROOT
+    fused: Dict[str, str] = {}           # fusion -> the computation it calls
     computation = None
     for line in text.splitlines():
         head = _COMPUTATION.match(line)
@@ -70,34 +78,34 @@ def instruction_scopes(text: str, names: Sequence[str]) -> Dict[str, str]:
         name, rest = m.groups()
         op = _OP_NAME.search(rest)
         own[name] = _innermost(op.group(1), names) if op else None
+        if op:
+            marked.add(name)
+        operands[name] = _OPERAND.findall(rest)
+        if line.lstrip().startswith("ROOT ") and computation is not None:
+            roots[computation] = name
         called = _CALLS.search(rest)
-        if called:
-            calls[name] = [c.strip().lstrip("%") for c in called.group(1).split(",")]
-        if computation is not None:
-            members[computation].append(name)
+        if called and " fusion(" in rest:
+            fused[name] = called.group(1)
 
-    counted: Dict[str, collections.Counter] = {}
+    feeding: Dict[str, set] = {}
 
-    def inside(comp: str) -> collections.Counter:
-        """Scopes of the instructions of ``comp`` and what they call."""
-        if comp not in counted:
-            counted[comp] = collections.Counter()  # a cycle reads as empty
-            total = collections.Counter()
-            for instr in members.get(comp, ()):
-                if own[instr] is not None:
-                    total[own[instr]] += 1
-                for sub in calls.get(instr, ()):
-                    total.update(inside(sub))
-            counted[comp] = total
-        return counted[comp]
+    def feeds(name: str) -> set:
+        """The scopes of the nearest instructions with an ``op_name`` that
+        the value of ``name`` is computed from, inside its computation."""
+        if name not in feeding:
+            feeding[name] = set()  # a cycle reads as empty
+            if name in marked:
+                found = {own[name] or UNSCOPED}
+            else:
+                found = set().union(*(feeds(o) for o in operands.get(name, ())))
+            feeding[name] = found
+        return feeding[name]
 
     out = {}
     for name, scope in own.items():
-        if scope is None and name in calls:
-            fused = collections.Counter()
-            for comp in calls[name]:
-                fused.update(inside(comp))
-            scope = fused.most_common(1)[0][0] if fused else None
+        if name not in marked and name in fused:
+            found = feeds(roots.get(fused[name], ""))
+            scope = next(iter(found)) if len(found) == 1 else None
         out[name] = scope or UNSCOPED
     return out
 
@@ -161,21 +169,18 @@ def split(trace, program: str, scopes: Dict[str, str]) -> Optional[Tuple[Dict[st
 # -- the serving cells' decode step -------------------------------------------------
 
 
-def decode_text(cell, device=None) -> str:
-    """The compiled HLO text of the serving cell's decode step, at the
-    shapes the window runs it (``slots`` tokens against a full cache)."""
+def decode_text(cfg, slots: int, max_len: int, device=None) -> str:
+    """The compiled HLO text of ``Server``'s decode step for the model
+    ``cfg``: ``slots`` tokens against a cache of ``max_len`` positions, on
+    ``device`` (the first of JAX's by default)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from chipbench.kinds.serve import program_config
     from repro.models import Model
     from repro.runtime.serving import ServeConfig, Server
 
     chip = SingleDeviceSharding(device if device is not None else jax.devices()[0])
-    cfg = program_config(cell.config)
-    opts = cell.config["serve"]
-    slots, max_len = int(opts["slots"]), int(opts["max_len"])
 
     def on_chip(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree)
@@ -188,6 +193,15 @@ def decode_text(cell, device=None) -> str:
         one))
     tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=chip)
     return server._decode.lower(params, tokens, state).compile().as_text()
+
+
+def cell_decode_text(cell, device=None) -> str:
+    """:func:`decode_text` at the serving cell's sizes, which its window runs."""
+    from chipbench.kinds.serve import program_config
+
+    opts = cell.config["serve"]
+    return decode_text(program_config(cell.config), int(opts["slots"]), int(opts["max_len"]),
+                       device)
 
 
 def model_scope_names() -> Optional[Tuple[str, ...]]:
@@ -209,7 +223,7 @@ def decode_split(run) -> Optional[Tuple[Dict[str, float], int]]:
     result = None
     names = model_scope_names()
     if run.trace is not None and run.trace.devices and names is not None:
-        text = decode_text(run.cell)
+        text = cell_decode_text(run.cell)
         if any(f"/{n}/" in text for n in names):
             result = split(run.trace, DECODE_PROGRAM, instruction_scopes(text, names))
     _last[:] = [weakref.ref(run), result]

@@ -47,6 +47,7 @@ from typing import Dict, List, Optional, Tuple  # noqa: E402
 
 import numpy as np  # noqa: E402
 
+from chipbench import gaps  # noqa: E402
 from chipbench.scopes import DECODE_PROGRAM  # noqa: E402
 
 PROGRAM_PREFIX = "serve"
@@ -121,37 +122,35 @@ def _overlap(lo: int, hi: int, intervals: List[Tuple[int, int]]) -> int:
 
 
 def decode_gaps(trace, spans: List[HostSpan], skew: int) -> List[Dict[str, float]]:
-    """For each pair of consecutive decode programs inside the window whose
-    first ran in a ``serve.step`` with ``refills == 0``: the device-idle ms
-    between them, and how much of it the host spent in ``serve.sample``, in
-    ``serve.decode``, and in neither (device times shifted by ``skew``)."""
-    programs = [(s + skew, e + skew) for s, e in _decode_programs(trace)]
+    """For each pair of decode programs inside the window that ran in
+    consecutive ``serve.step`` spans of one ``serve`` call, the first with
+    ``refills == 0``: the device-idle ms between them, and how much of it
+    the host spent in ``serve.sample``, in ``serve.decode``, and in neither
+    (device times shifted by ``skew``).  The gaps of
+    :func:`chipbench.gaps.refill_free_gaps`, chosen by the host's spans
+    instead of the device's programs."""
+    programs = _decode_programs(trace)
     lo, hi = trace.window
-    busy = [(s + skew, e + skew) for s, e in trace.busy_intervals(trace.devices[0])]
-    busy_ends = [e for _, e in busy]
+    busy = trace.busy_intervals(trace.devices[0])
     steps = [(s, e, attrs) for n, s, e, attrs in spans if n == "serve.step"]
     step_starts = [s for s, _, _ in steps]
     sample = [(s, e) for n, s, e, _ in spans if n == "serve.sample"]
     decode = [(s, e) for n, s, e, _ in spans if n == "serve.decode"]
+
+    def step_of(t):
+        i = bisect_left(step_starts, t + skew + 1) - 1
+        return steps[i][2] if i >= 0 and steps[i][0] <= t + skew < steps[i][1] else None
+
     out = []
-    for (s0, e0), (s1, _) in zip(programs, programs[1:]):
-        if s0 < lo or s1 > hi:
+    for (s0, e0), (s1, e1) in zip(programs, programs[1:]):
+        if s0 < lo or e1 > hi:
             continue
-        i = bisect_left(step_starts, s0 + 1) - 1
-        if i < 0 or not (steps[i][0] <= s0 < steps[i][1]):
+        first, second = step_of(s0), step_of(s1)
+        if first is None or second is None or int(first.get("refills", -1)) != 0:
             continue
-        if int(steps[i][2].get("refills", -1)) != 0:
-            continue
-        idle = []
-        t = e0
-        for bs, be in busy[bisect_left(busy_ends, e0 + 1):]:
-            if bs >= s1:
-                break
-            if bs > t:
-                idle.append((t, bs))
-            t = max(t, be)
-        if s1 > t:
-            idle.append((t, s1))
+        if int(second.get("step", -1)) != int(first.get("step", -1)) + 1:
+            continue  # the next serve call's first step, after its fills
+        idle = [(a + skew, b + skew) for a, b in gaps.idle_pieces(busy, e0, s1)]
         total = sum(b - a for a, b in idle)
         in_sample = sum(_overlap(a, b, sample) for a, b in idle)
         in_decode = sum(_overlap(a, b, decode) for a, b in idle)
@@ -221,7 +220,7 @@ def main(argv=None) -> int:
     tr = trace.from_profile(data)
     spans = program_spans(data)
     cell = bench.Cell.load(args.workload, args.root)
-    text = scopes.decode_text(cell, jax.devices()[0])
+    text = scopes.cell_decode_text(cell, jax.devices()[0])
     (args.out / "decode.hlo.txt").write_text(text)
 
     names = scopes.instruction_scopes(text, model_scopes.ALL)

@@ -9,9 +9,10 @@ import pathlib
 import time
 
 import jax
+import numpy as np
 import pytest
 
-from chipbench import bench, scopes, timeline
+from chipbench import bench, gaps, scopes, timeline
 from chipbench.trace import Device, Trace
 from repro import obs
 from repro.models import scopes as model_scopes
@@ -34,7 +35,15 @@ _TEXT = """HloModule jit__decode_step, entry_computation_layout={(f32[4]{0})->f3
 %fused_computation (param_0: f32[4]) -> f32[4] {
   %param_0 = f32[4]{0} parameter(0)
   %dynamic-update-slice.1 = f32[4]{0} dynamic-update-slice(%param_0), metadata={op_name="jit(_decode_step)/while/body/closed_call/kv_carry/scatter" stack_frame_id=3}
-  ROOT %convert.1 = f32[4]{0} convert(%dynamic-update-slice.1), metadata={op_name="jit(_decode_step)/while/body/closed_call"}
+  ROOT %convert.1 = f32[4]{0} convert(%dynamic-update-slice.1)
+}
+
+%fused_computation.3 (param_0.2: f32[4]) -> (f32[4], f32[4]) {
+  %param_0.2 = f32[4]{0} parameter(0)
+  %exp.3 = f32[4]{0} exponential(%param_0.2), metadata={op_name="jit(_decode_step)/while/body/closed_call/attention/exp"}
+  %mul.3 = f32[4]{0} multiply(%param_0.2, %param_0.2), metadata={op_name="jit(_decode_step)/while/body/closed_call/mlp/mul"}
+  %convert.3 = f32[4]{0} convert(%mul.3)
+  ROOT %tuple.3 = (f32[4]{0}, f32[4]{0}) tuple(%exp.3, %convert.3)
 }
 
 %fused_computation.2 (param_0.1: f32[4]) -> f32[4] {
@@ -45,8 +54,10 @@ _TEXT = """HloModule jit__decode_step, entry_computation_layout={(f32[4]{0})->f3
 
 %body (p: f32[4]) -> f32[4] {
   %p = f32[4]{0} parameter(0)
-  %fusion.1 = f32[4]{0} fusion(%p), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(_decode_step)/while/body/closed_call"}
+  %fusion.1 = f32[4]{0} fusion(%p), kind=kLoop, calls=%fused_computation
   %fusion.2 = f32[4]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2
+  %fusion.7 = f32[4]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(_decode_step)/while/body/closed_call"}
+  %fusion.8 = (f32[4]{0}, f32[4]{0}) fusion(%fusion.2), kind=kLoop, calls=%fused_computation.3
   %copy.3 = f32[4]{0} copy(%fusion.2), metadata={op_name="jit(_decode_step)/while/body/closed_call/attention/while/body/mlp/dot_general"}
   ROOT %copy.4 = f32[4]{0} copy(%copy.3)
 }
@@ -63,11 +74,14 @@ def test_instruction_scopes_take_the_innermost_and_look_into_fusions():
     found = scopes.instruction_scopes(_TEXT, model_scopes.ALL)
     # own metadata first, innermost scope of its op_name
     assert found["copy.3"] == "mlp" and found["fusion.6"] == "lm_head"
-    # a fusion whose own metadata names no scope, or which has none, takes
-    # the scope of what is fused into it
+    # a fusion with no metadata of its own takes the scope its root is
+    # computed in, through fused instructions that have none
     assert found["fusion.1"] == "kv_carry" and found["fusion.2"] == "attention"
-    # no metadata, or metadata in no scope: unscoped
+    # ... and none where its root is computed from two scopes
+    assert found["fusion.8"] == scopes.UNSCOPED
+    # no metadata, or metadata in no scope, fusions included: unscoped
     assert found["copy.4"] == found["while.5"] == found["p"] == scopes.UNSCOPED
+    assert found["fusion.7"] == scopes.UNSCOPED
     assert found["exp.2"] == "attention"
 
 
@@ -116,7 +130,7 @@ def _run(trace):
 
 def test_decode_readers_split_the_compiled_decode_step():
     cell = bench.Cell.load("tiny.mix", TINY)
-    names = scopes.instruction_scopes(scopes.decode_text(cell), model_scopes.ALL)
+    names = scopes.instruction_scopes(scopes.cell_decode_text(cell), model_scopes.ALL)
     pick = {scope: next(n for n, s in names.items() if s == scope)
             for scope in ("kv_carry", "kv_write", "attention", "unscoped")}
     # two decode programs of 100 ns: 10 ns of kv_write, 20 of kv_carry, 30 of
@@ -167,7 +181,8 @@ def _timeline_trace():
     programs = [("jit__decode_step(2)", s * us, e * us) for s, e in _PROGRAMS]
     programs += [("jit__argmax(4)", s * us, e * us) for s, e in _ARGMAX]
     ops = [(f"%fusion.{i} = f32[4]{{0}} fusion(%x)", s, e) for i, (_, s, e) in enumerate(programs)]
-    spans = [("serve.step", s * us, e * us, {"refills": r}) for s, e, r in _STEPS]
+    spans = [("serve.step", s * us, e * us, {"step": k, "refills": r})
+             for k, (s, e, r) in enumerate(_STEPS)]
     spans += [("serve.decode", s * us, e * us, {}) for s, e in _DECODE_SPANS]
     spans += [("serve.sample", s * us, e * us, {}) for s, e in _SAMPLE_SPANS]
     spans.sort(key=lambda sp: sp[1])
@@ -197,6 +212,46 @@ def test_decode_gaps_follow_steps_without_refills_and_split_by_span():
     # the longest gap, 64-75 on the device's clock, is in the second step
     longest = timeline.longest_gaps(trace, spans, 1000, n=2)
     assert longest[0][0] == "serve.step" and longest[0][1] == pytest.approx(0.011)
+
+
+def _with_refill(trace):
+    """The timeline trace with the refill its second step made: a prefill
+    at 66-69 us and a slot insert at 70-71 us, device clock."""
+    us = 1000
+    device = trace.devices[0]
+    refill = [("jit__prefill_fn(5)", 66 * us, 69 * us), ("jit__insert_slot(6)", 70 * us, 71 * us)]
+    ops = [(f"%fusion.{50 + i} = f32[4]{{0}} fusion(%x)", s, e)
+           for i, (_, s, e) in enumerate(refill)]
+    return Trace(window=trace.window, host_spans=trace.host_spans,
+                 devices=[Device(device.name, device.programs + refill, device.ops + ops)])
+
+
+def test_decode_gap_reader_skips_the_gaps_that_hold_a_refill():
+    trace = _with_refill(_timeline_trace()[0])
+    # the first gap alone: 28-31 and 32-40 us idle around the argmax; the
+    # second holds the refill, and the third program has no successor
+    assert gaps.decode_gap_ms(trace) == [pytest.approx(0.011)]
+    assert gaps.refill_free_gaps(trace) == [[(28000, 31000), (32000, 40000)]]
+    # without the refill's programs the second gap counts too: 64-75 us
+    assert gaps.decode_gap_ms(_timeline_trace()[0]) == [pytest.approx(0.011),
+                                                        pytest.approx(0.011)]
+    # a decode program past the window's end pairs with nothing
+    late = _with_refill(_timeline_trace()[0])
+    late.window = (0, 87000)
+    assert gaps.decode_gap_ms(late) == [pytest.approx(0.011)]
+    reader = bench.metric_reader("decode_gap_ms")
+    assert reader.read(_run(trace)) == pytest.approx(0.011)
+    assert reader.read(_run(None)) is None
+    assert reader.read(_run(Trace(window=(0, 1), host_spans=[], devices=[]))) is None
+
+
+def test_idle_pieces_leave_out_every_busy_interval():
+    busy = np.array([[0, 10], [15, 20], [30, 40]], np.int64)
+    assert gaps.idle_pieces(busy, 5, 35) == [(10, 15), (20, 30)]
+    assert gaps.idle_pieces(busy, 10, 15) == [(10, 15)]
+    assert gaps.idle_pieces(busy, 40, 50) == [(40, 50)]
+    assert gaps.idle_pieces(busy, 31, 39) == []
+    assert gaps.idle_pieces(np.zeros((0, 2), np.int64), 1, 3) == [(1, 3)]
 
 
 def test_program_spans_come_back_from_a_profiler_trace(tmp_path):
@@ -269,7 +324,21 @@ def test_recorded_scoped_trace_carries_the_servers_spans(scoped):
     # benchmark's own spans showed on the first recorded trace
     lower, upper = timeline.skew_bounds_ns(tr, spans)
     assert 1.0e6 < lower < upper < 2.0e6
-    gaps = timeline.decode_gaps(tr, spans, lower)
-    assert len(gaps) == 9 and all(g["idle_ms"] > 0 for g in gaps)
-    for g in gaps:
+    found = timeline.decode_gaps(tr, spans, lower)
+    # two calls of six steps: ten pairs of steps in one call, two of them
+    # with refills
+    assert len(found) == 8 and all(g["idle_ms"] > 0 for g in found)
+    for g in found:
         assert g["sample_ms"] + g["decode_ms"] + g["neither_ms"] == pytest.approx(g["idle_ms"])
+
+
+def test_recorded_scoped_trace_gaps_by_program_are_the_gaps_by_span(scoped):
+    # the decode gap reader picks its gaps from the device's programs alone;
+    # the Server's serve.step spans pick the same eight, whatever the shift
+    tr, spans, _ = scoped
+    by_program = gaps.decode_gap_ms(tr)
+    for shift in timeline.skew_bounds_ns(tr, spans):
+        by_span = [g["idle_ms"] for g in timeline.decode_gaps(tr, spans, shift)]
+        assert by_program == pytest.approx(by_span)
+    assert bench.metric_reader("decode_gap_ms").read(_run(tr)) == pytest.approx(
+        float(np.median(by_program)))

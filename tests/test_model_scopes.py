@@ -8,23 +8,17 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from chipbench import scopes as chipbench_scopes
 from repro.configs import reduced_config
 from repro.models import Model, scopes
-from repro.runtime.serving import ServeConfig, Server
 
 SLOTS, MAX_LEN = 2, 32
 
 
 def _decode_text(cfg) -> str:
-    """The compiled text of ``Server``'s decode step at test sizes."""
-    server = Server(cfg, ServeConfig(batch_slots=SLOTS, max_len=MAX_LEN), None)
-    params = jax.eval_shape(lambda k: server.model.init(k)[0], jax.random.PRNGKey(0))
-    one = jax.eval_shape(server._prefill_fn, params, jax.ShapeDtypeStruct((1, 5), jnp.int32))[1]
-    state = Server._tree_map_batch(
-        lambda x, ax: jax.ShapeDtypeStruct(x.shape[:ax] + (SLOTS,) + x.shape[ax + 1:], x.dtype),
-        one)
-    tokens = jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32)
-    return server._decode.lower(params, tokens, state).compile().as_text()
+    """The compiled text of ``Server``'s decode step at test sizes, built as
+    the benchmark builds it for its cells."""
+    return chipbench_scopes.decode_text(cfg, SLOTS, MAX_LEN)
 
 
 def _scopes_in(text: str):
