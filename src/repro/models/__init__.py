@@ -209,21 +209,16 @@ class Model:
         raise ValueError(cfg.family)
 
     def decode_step(self, params: Pytree, tokens: jax.Array, state: Dict[str, Any]):
-        """One new token per sequence against the cached state."""
+        """One new token per sequence against the cached state.
+
+        For the ``dense``/``moe``/``vlm`` families the token shape chooses
+        the attention, not ``attn_impl``: :func:`transformer.decode` reads
+        each layer's cache in place and writes only the new token's rows."""
         cfg = self.cfg
         B = tokens.shape[0]
         positions = state["pos"][:, None]
         if cfg.family in ("dense", "moe", "vlm"):
-            kv = state["kv"]
-            max_len = kv[0].shape[2]
-            cache_pos = jnp.broadcast_to(
-                jnp.arange(max_len, dtype=jnp.int32)[None], (B, max_len)
-            )
-            h, new_kv = transformer.forward(
-                cfg, params, tokens, positions=positions,
-                attn_impl=self.attn_impl,
-                kv_caches=kv, cache_positions=cache_pos,
-            )
+            h, new_kv = transformer.decode(cfg, params, tokens, state["pos"], state["kv"])
             return h, {"kv": new_kv, "pos": state["pos"] + 1}
         if cfg.family == "ssm":
             h, st = self._ssm_forward(

@@ -12,6 +12,9 @@
 
 All paths share the GQA head-grouping and mask conventions and are tested
 allclose against each other.
+
+Decoding one token per sequence against a KV cache takes
+:func:`attention_decode` whatever the ``impl``: the shape chooses it.
 """
 
 from __future__ import annotations
@@ -122,6 +125,57 @@ def attention_chunked(
     l = jnp.maximum(l, 1e-30)
     out = acc / l.transpose(0, 2, 1)[..., None]
     return out.astype(q.dtype)
+
+
+def attention_decode(
+    q: jax.Array,  # (B, 1, Hq, Dh): one query token per sequence
+    k: jax.Array,  # (B, 1, Hkv, Dh): that token's own key
+    v: jax.Array,  # (B, 1, Hkv, Dh)
+    k_cache: jax.Array,  # (B, Skv, Hkv, Dh)
+    v_cache: jax.Array,  # (B, Skv, Hkv, Dh)
+    positions: jax.Array,  # (B,) each token's position
+    window: Optional[jax.Array] = None,
+    chunk_attn: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """One token per sequence against its cache, with the cache read where
+    it is stored.
+
+    Row ``b`` attends to the cache positions strictly before
+    ``positions[b]`` (under the window and chunk masks) and to its own
+    ``k``/``v``, in one float32 softmax; what the cache holds at and after
+    ``positions[b]`` takes no part.  Scores and the weighted sum are float32
+    multiply-and-reduce over ``Dh`` and over positions, and query heads are
+    grouped over their shared KV head: no dot operand, reshape or GQA
+    broadcast of the cache whose layout could differ from the cache's own,
+    so the compiler has no reason to copy a cache slice.  -> (B, 1, Hq, Dh)
+    """
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    b, _, hq, dh = q.shape
+    skv, hkv = k_cache.shape[1], k_cache.shape[2]
+    qf = q.reshape(b, hkv, hq // hkv, dh).astype(jnp.float32)  # (B, Hkv, G, Dh)
+
+    t = jnp.arange(skv, dtype=positions.dtype)[None, :]
+    pos = positions[:, None]
+    ok = t < pos
+    if window is not None:
+        ok = jnp.logical_and(ok, t > pos - window)
+    if chunk_attn is not None:
+        ok = jnp.logical_and(ok, t // chunk_attn == pos // chunk_attn)
+    ok = ok[:, :, None, None]  # (B, Skv, 1, 1)
+
+    kf = k_cache[:, :, :, None, :].astype(jnp.float32)  # (B, Skv, Hkv, 1, Dh)
+    logits = jnp.sum(qf[:, None] * kf, axis=-1) * scale  # (B, Skv, Hkv, G)
+    own = jnp.sum(qf * k[:, 0, :, None, :].astype(jnp.float32), axis=-1) * scale
+    m = jnp.maximum(jnp.max(jnp.where(ok, logits, NEG_INF), axis=1), own)  # (B, Hkv, G)
+    p = jnp.where(ok, jnp.exp(logits - m[:, None]), 0.0)
+    p_own = jnp.exp(own - m)
+    l = jnp.sum(p, axis=1) + p_own
+    vf = v_cache[:, :, :, None, :].astype(jnp.float32)
+    acc = jnp.sum(p[..., None] * vf, axis=1)  # (B, Hkv, G, Dh)
+    acc = acc + p_own[..., None] * v[:, 0, :, None, :].astype(jnp.float32)
+    out = acc / l[..., None]
+    return out.reshape(b, 1, hq, dh).astype(q.dtype)
 
 
 def attention(

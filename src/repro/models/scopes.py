@@ -10,10 +10,13 @@ copy the compiler inserted.
 
 #: pre-attention norm, the q/k/v projections and rotary embedding
 QKV = "qkv"
-#: the current token's k and v written into one layer's cache slice
+#: the tokens' k and v written into the cache: in decode, every layer's new
+#: rows written into the stacked cache after the layer scan, in place; in
+#: prefill, the prompt written into one layer's cache slice
 KV_WRITE = "kv_write"
-#: one layer's cache slice read out of, and written back into, the stacked
-#: cache the layer scan carries
+#: one layer's cache slice taken from the stacked cache: in decode, read in
+#: place by attention; in prefill, read out of and written back into the
+#: stacked cache the layer scan carries
 KV_CARRY = "kv_carry"
 #: attention over the cache and the output projection
 ATTENTION = "attention"

@@ -11,7 +11,8 @@ granite-8b, qwen2-moe-a2.7b, llama4-scout and the qwen2-vl-2b backbone:
 * layer stacks are scanned (``jax.lax.scan``) over stacked parameters:
   HLO size is O(1) in depth, which keeps the 512-device dry-run tractable;
 * three step flavours: ``train`` (full seq), ``prefill`` (returns KV cache),
-  ``decode`` (one token against the cache).
+  ``decode`` (one token against the cache, which it reads in place and
+  writes only at the token's rows).
 
 Parameters are plain pytrees; a parallel *logical-axes* pytree drives
 sharding (:mod:`repro.sharding`).
@@ -27,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from . import scopes
-from .attention import attention
+from .attention import attention, attention_decode
 from .common import scan as common_scan, apply_mrope, apply_rope, rms_norm, swiglu, trunc_normal
 
 Pytree = Any
@@ -333,7 +334,7 @@ def block(
 
     if kv_cache is not None:
         ck, cv = kv_cache  # (B, Skv, Hkv, Dh)
-        # decode: insert current token(s) at their positions
+        # prefill: insert the tokens at their positions, attend over the cache
         with jax.named_scope(scopes.KV_WRITE):
             upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice(c, u, (p, 0, 0)))
             ck = upd(ck, k.astype(ck.dtype), positions[:, 0])
@@ -354,15 +355,43 @@ def block(
         )
         B, S = h.shape[:2]
         h = h + (o.reshape(B, S, -1) @ lp["wo"]).astype(h.dtype)
+    return _mlp(cfg, h, lp), new_cache
 
+
+def _mlp(cfg: ModelConfig, h: jax.Array, lp: Dict[str, jax.Array]) -> jax.Array:
     with jax.named_scope(scopes.MLP):
         x = rms_norm(h, lp["ln2"])
         if cfg.moe is None:
             y = swiglu(x @ lp["w_gate"], x @ lp["w_up"]) @ lp["w_down"]
         else:
             y = moe_ffn(x.reshape(-1, cfg.d_model), lp, cfg.moe).reshape(x.shape)
-        h = h + y.astype(h.dtype)
-    return h, new_cache
+        return h + y.astype(h.dtype)
+
+
+def decode_block(
+    cfg: ModelConfig,
+    h: jax.Array,  # (B, 1, D)
+    lp: Dict[str, jax.Array],
+    kind: jax.Array,
+    positions: jax.Array,  # (B, 1)
+    ck: jax.Array,  # (B, Skv, Hkv, Dh): this layer's cache, read only
+    cv: jax.Array,
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """:func:`block` for one token per sequence against a layer's cache,
+    which it only reads; returns (h, the token's (k, v) in the cache's
+    dtype) for the caller to write."""
+    with jax.named_scope(scopes.QKV):
+        x = rms_norm(h, lp["ln1"])
+        q, k, v = _qkv(x, lp, cfg)
+        q = _rope(cfg, q, positions, kind)
+        k = _rope(cfg, k, positions, kind).astype(ck.dtype)
+        v = v.astype(cv.dtype)
+
+    window, chunk = _mask_params(cfg, kind)
+    with jax.named_scope(scopes.ATTENTION):
+        o = attention_decode(q, k, v, ck, cv, positions[:, 0], window=window, chunk_attn=chunk)
+        h = h + (o.reshape(h.shape[0], 1, -1) @ lp["wo"]).astype(h.dtype)
+    return _mlp(cfg, h, lp), (k, v)
 
 
 def _split_moe_keys(cfg: ModelConfig, lp: Dict[str, jax.Array]):
@@ -373,7 +402,6 @@ def forward(
     cfg: ModelConfig,
     params: Pytree,
     tokens: jax.Array,  # (B, S) int32
-    positions: Optional[jax.Array] = None,
     attn_impl: str = "chunked",
     remat: str = "none",  # none | dots | full
     patch_embeds: Optional[jax.Array] = None,
@@ -381,7 +409,8 @@ def forward(
     kv_caches: Optional[Tuple[jax.Array, jax.Array]] = None,  # (L,B,Skv,Hkv,Dh) x2
     cache_positions: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
-    """Returns (final hidden states (B,S,D), stacked new KV caches or None)."""
+    """The sequences from position 0 (training, prefill); returns (final
+    hidden states (B,S,D), stacked new KV caches or None)."""
     B, S = tokens.shape
     h = params["embed"][tokens].astype(cfg.dtype)
     if cfg.family == "vlm" and patch_embeds is not None:
@@ -389,8 +418,7 @@ def forward(
         P = patch_embeds.shape[1]
         proj = (patch_embeds.astype(cfg.dtype) @ params["patch_proj"]).astype(cfg.dtype)
         h = jnp.concatenate([proj, h[:, P:]], axis=1)
-    if positions is None:
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
 
     kinds = cfg.layer_kinds()
 
@@ -434,6 +462,54 @@ def forward(
         new_caches = (ck, cv)
     else:
         h, new_caches = common_scan(body, h, (params["layers"], kinds))
+
+    h = rms_norm(h, params["final_ln"])
+    return h, new_caches
+
+
+def decode(
+    cfg: ModelConfig,
+    params: Pytree,
+    tokens: jax.Array,  # (B, 1) int32
+    positions: jax.Array,  # (B,) each token's position
+    kv_caches: Tuple[jax.Array, jax.Array],  # (L,B,Skv,Hkv,Dh) x2
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """One token per sequence against the stacked caches; returns (final
+    hidden states (B,1,D), the caches with the token's k/v written).
+
+    The caches are not carried through the layer scan, where the compiler
+    copies each layer's slice out and back: each layer reads its slice where
+    it is stored (:func:`attention_decode`), and the layers' new rows come
+    out of the scan and are written after it, slot by slot, in place when
+    the caches are donated.  One scatter of all the rows would make the
+    compiler copy both caches into another layout around it.
+    """
+    B, S = tokens.shape
+    if S != 1:
+        raise ValueError(f"decode takes one token per sequence, got {S}")
+    h = params["embed"][tokens].astype(cfg.dtype)
+    ck_all, cv_all = kv_caches
+
+    def scan_body(h, xs):
+        lp, kind, i = xs
+        with jax.named_scope(scopes.KV_CARRY):
+            ck, cv = ck_all[i], cv_all[i]
+        return decode_block(cfg, h, lp, kind, positions[:, None], ck, cv)
+
+    xs = (params["layers"], cfg.layer_kinds(), jnp.arange(cfg.n_layers))
+    h, (k_new, v_new) = common_scan(scan_body, h, xs)  # (L, B, 1, Hkv, Dh) x2
+
+    with jax.named_scope(scopes.KV_WRITE):
+        def write(b, caches):
+            at = (0, b, positions[b], 0, 0)
+            return tuple(
+                jax.lax.dynamic_update_slice(c, jax.lax.dynamic_slice_in_dim(new, b, 1, 1), at)
+                for c, new in zip(caches, (k_new, v_new))
+            )
+
+        # unrolled: for caches with a head size of 128 the TPU compiler
+        # fails (an internal RET_CHECK) on a rolled loop here
+        new_caches = jax.lax.fori_loop(0, B, write, (ck_all, cv_all), unroll=True)
 
     h = rms_norm(h, params["final_ln"])
     return h, new_caches

@@ -11,14 +11,19 @@ test worker imports every test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from chipbench.bench import Cell
+from chipbench.kinds.serve import program_config
 from repro.configs import get_config
 from repro.kernels import ops
+from repro.models import Model
+from repro.runtime.serving import ServeConfig, Server
 
 
 @pytest.fixture(scope="module")
@@ -82,3 +87,53 @@ def test_ssd_compiles_at_mamba2_widths(one_chip, dtype):
         _spec(one_chip, (1, s, n), dtype),
         chunk=MAMBA2.ssm_chunk,
     )
+
+
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _compile_decode_step(sharding, cfg, slots, max_len):
+    """``Server``'s decode step for ``cfg`` at ``slots`` x ``max_len``."""
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(sharding, x.shape, x.dtype), tree)
+
+    params = on_chip(Model(cfg).abstract_init()[0])
+    server = Server(cfg, ServeConfig(batch_slots=slots, max_len=max_len), params)
+    one = jax.eval_shape(server._prefill_fn, params, jax.ShapeDtypeStruct((1, 1), jnp.int32))[1]
+    state = on_chip(Server._tree_map_batch(
+        lambda x, ax: jax.ShapeDtypeStruct(x.shape[:ax] + (slots,) + x.shape[ax + 1:], x.dtype),
+        one))
+    tokens = _spec(sharding, (slots, 1), jnp.int32)
+    return server._decode.lower(params, tokens, state).compile()
+
+
+def test_serving_decode_step_moves_no_cache_sized_data(one_chip):
+    """``Server``'s decode step at the stablelm_3b serving cell's sizes (12
+    slots x 2048, full widths) reads each layer's cache slice where it is
+    stored and writes only the token's rows: no copy, select, scatter or
+    transpose yields a layer's slice or the stacked cache, and the step
+    needs under 64 MiB of temporaries beside its arguments."""
+    cell = Cell.load("stablelm_3b.decode_heavy")
+    cfg = program_config(cell.config)
+    slots, max_len = int(cell.config["serve"]["slots"]), int(cell.config["serve"]["max_len"])
+    compiled = _compile_decode_step(one_chip, cfg, slots, max_len)
+
+    slice_dims = (slots, max_len, cfg.n_kv_heads, cfg.dh)
+    cache_sized = {slice_dims, (1,) + slice_dims, (cfg.n_layers,) + slice_dims}
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) in ("copy", "select", "scatter", "transpose"):
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            if dims in cache_sized:
+                moved.append(line.strip()[:160])
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_decode_step_compiles_for_gqa_at_head_size_128(one_chip):
+    """qwen2_7b's decode step (28 query heads over 4 KV heads of 128) at full
+    widths, on a small cache: the per-slot write of the new rows compiles
+    where a rolled loop of it hit an internal error of the TPU compiler."""
+    compiled = _compile_decode_step(one_chip, get_config("qwen2_7b"), 4, 256)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20

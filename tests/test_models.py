@@ -16,6 +16,7 @@ import pytest
 from repro.configs import ARCH_IDS, get_config, param_count, reduced_config, shape_cells
 from repro.models import Model, transformer
 from repro.models.attention import attention_chunked, attention_xla
+from repro.runtime.serving import Server
 
 
 def _batch_for(cfg, B, S, key):
@@ -91,6 +92,51 @@ def test_decode_consistency_with_forward(arch):
     h_dec, _ = model.decode_step(params, toks[:, S : S + 1], state)
     err = float(jnp.abs(h_dec[:, 0] - h_full[:, S]).max())
     assert err < 5e-2, err  # bf16 cache quantization bound
+
+
+@pytest.mark.parametrize("lengths", [(5, 21, 32, 38), (0, 21)], ids=["ragged", "empty_slot"])
+@pytest.mark.parametrize(
+    "arch", ["stablelm_3b", "qwen2_7b", "gemma3_1b", "llama4_scout_17b_a16e"]
+)
+def test_decode_at_ragged_positions_matches_forward(arch, lengths):
+    """Rows prefilled to different lengths and placed into one batch state,
+    as ``Server`` places them, each decode one token that matches the full
+    forward over the row's own sequence.  The lengths cross gemma3's window
+    (16) and llama4's attention chunk (32); a slot never filled sits at
+    position 0 and attends to its token alone."""
+    cfg = dataclasses.replace(reduced_config(arch), dtype=jnp.float32)
+    model = Model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(0))
+    max_len = 40
+    toks = jax.random.randint(jax.random.PRNGKey(1), (len(lengths), max_len), 1, cfg.vocab)
+    prefill = jax.jit(lambda p, t: model.prefill(p, {"tokens": t}, max_len)[1])
+    state = None
+    for slot, n in enumerate(lengths):
+        if n == 0:
+            continue
+        one = prefill(params, toks[slot:slot + 1, :n])
+        if state is None:
+            state = Server._tree_map_batch(
+                lambda x, ax: jnp.zeros(
+                    x.shape[:ax] + (len(lengths),) + x.shape[ax + 1:], x.dtype
+                ),
+                one,
+            )
+        state = Server._insert_slot(state, one, jnp.int32(slot))
+    # two steps, so the second reads the rows the first wrote
+    decode = jax.jit(model.decode_step)
+    rows, pos = jnp.arange(len(lengths)), jnp.array(lengths)
+    h_dec = []
+    for step in range(2):
+        h, state = decode(params, toks[rows, pos + step][:, None], state)
+        assert bool(jnp.all(jnp.isfinite(h)))
+        h_dec.append(h[:, 0])
+    assert [int(p) for p in state["pos"]] == [n + 2 for n in lengths]
+    for slot, n in enumerate(lengths):
+        h_full, _ = transformer.forward(cfg, params, toks[slot:slot + 1, :n + 2], attn_impl="xla")
+        for step in range(2):
+            err = float(jnp.abs(h_dec[step][slot] - h_full[0, n + step]).max())
+            assert err < 5e-2, (slot, n, step, err)  # bf16 cache quantization bound
 
 
 def test_full_configs_match_assignment():
