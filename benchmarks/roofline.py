@@ -114,9 +114,7 @@ def analytic_cell(arch: str, cell_name: str, mesh: str,
     if cell.kind == "decode" and cfg.n_heads:
         kv_total = 2 * cfg.n_layers * B * S * cfg.n_kv_heads * cfg.dh * 2
         if cfg.family == "hybrid":
-            from repro.models.hybrid import n_attn_applications
-
-            kv_total = 2 * n_attn_applications(cfg) * B * S * cfg.n_kv_heads * cfg.dh * 2
+            kv_total = 2 * len(cfg.hybrid_layer_ids) * B * S * cfg.n_kv_heads * cfg.dh * 2
         cache_traffic = kv_total / nchips
     hbm_dev = act_traffic + param_traffic + cache_traffic
 

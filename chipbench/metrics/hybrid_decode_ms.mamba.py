@@ -1,0 +1,19 @@
+"""Decode step of a Zamba2 cell, by named scope: device ms per ``_decode_step``
+program in ``mamba``, every Mamba2 layer outside its scan: the input norm,
+``in_proj``, the conv with its state, the gated norm and ``out_proj``.  Read
+from the traced window's operations and this kind's decode step compiled
+again for the text (``chipbench/kinds/serve_zamba2.py:decode_split``); none
+where the program has no scopes or the text matches under 99% of the
+program's operation time."""
+
+from chipbench.kinds import serve_zamba2
+
+UNIT = "ms"
+SOURCE = "device_trace"
+LAYER = "model ops (decode program)"
+MOVES = "serve_tokens_per_s"
+SCOPE = "mamba"
+
+
+def read(run):
+    return serve_zamba2.decode_ms(run, SCOPE)

@@ -108,21 +108,21 @@ def param_count(cfg: ModelConfig) -> int:
             if m.n_shared:
                 per += 3 * D * m.d_ff_shared + (D if m.shared_gate else 0)
         total += L * per
-    elif cfg.family == "ssm":
-        from repro.models.mamba2 import mamba_dims
+    elif cfg.family in ("ssm", "hybrid"):
+        from repro.models.mamba2 import D_CONV, mamba_dims
 
-        d_inner, conv_dim = mamba_dims(D, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
-        proj = 2 * d_inner + 2 * cfg.ssm_state + cfg.ssm_heads
-        per = D * proj + 4 * conv_dim + d_inner * D + d_inner + D
+        G, H = cfg.ssm_groups, cfg.ssm_heads
+        d_inner, conv_dim = mamba_dims(D, H, cfg.ssm_head_dim, cfg.ssm_state, G)
+        proj = 2 * d_inner + 2 * G * cfg.ssm_state + H
+        per = D * proj + (D_CONV + 1) * conv_dim + 3 * H + d_inner * D + d_inner + D
         total += L * per
-    elif cfg.family == "hybrid":
-        from repro.models.mamba2 import mamba_dims
-
-        d_inner, conv_dim = mamba_dims(D, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
-        proj = 2 * d_inner + 2 * cfg.ssm_state + cfg.ssm_heads
-        per = D * proj + 4 * conv_dim + d_inner * D + d_inner + D
-        total += L * per
-        total += D * Hq * Dh + 2 * D * Hkv * Dh + Hq * Dh * D + 3 * D * F  # shared blk
+    if cfg.family == "hybrid":
+        # shared blocks over [h ; e] (2D wide), and each application's
+        # adapter and output linear
+        r = cfg.adapter_rank
+        block = 2 * D + 2 * D * Dh * (Hq + 2 * Hkv) + Hq * Dh * D + D + 3 * D * F
+        app = r * (D + 2 * F) + D * D
+        total += cfg.num_mem_blocks * block + len(cfg.hybrid_layer_ids) * app + D
     elif cfg.family == "audio":
         per_enc = D * Hq * Dh + 2 * D * Hkv * Dh + Hq * Dh * D + 3 * D * F
         per_dec = per_enc + D * Hq * Dh + 2 * D * Hkv * Dh + Hq * Dh * D

@@ -90,16 +90,12 @@ def decode_state_struct(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str,
         kv = S((cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.dh), jnp.bfloat16)
         st["kv"] = (kv, kv)
     elif cfg.family == "ssm":
-        d_inner, conv_dim = mamba_dims(cfg.d_model, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+        d_inner, conv_dim = mamba_dims(cfg.d_model, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                                       cfg.ssm_groups)
         st["ssm"] = S((cfg.n_layers, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
         st["conv"] = S((cfg.n_layers, batch, D_CONV - 1, conv_dim), jnp.bfloat16)
     elif cfg.family == "hybrid":
-        apps = hybrid_mod.n_attn_applications(cfg)
-        d_inner, conv_dim = mamba_dims(cfg.d_model, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
-        kv = S((apps, batch, max_len, cfg.n_kv_heads, cfg.dh), jnp.bfloat16)
-        st["kv"] = (kv, kv)
-        st["ssm"] = S((cfg.n_layers, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
-        st["conv"] = S((cfg.n_layers, batch, D_CONV - 1, conv_dim), jnp.bfloat16)
+        st.update(jax.eval_shape(lambda: hybrid_mod.init_state(cfg, batch, max_len)))
     elif cfg.family == "audio":
         kv = S((cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.dh), jnp.bfloat16)
         st["kv"] = (kv, kv)
@@ -160,11 +156,11 @@ def state_shardings(cfg: ModelConfig, mesh: Mesh, state: Dict[str, Any], batch: 
             else:
                 spec = P(None, dp, seq_axes, None, None)
             out[key] = (NamedSharding(mesh, spec), NamedSharding(mesh, spec))
-        elif key == "ssm":
+        elif key == "ssm":  # one array, or the hybrid's one per segment
             spec = P(None, dp, "model" if ssm_shardable else None, None, None)
-            out[key] = NamedSharding(mesh, spec)
+            out[key] = jax.tree.map(lambda _: NamedSharding(mesh, spec), leaf)
         elif key == "conv":
-            out[key] = NamedSharding(mesh, P(None, dp, None, "model"))
+            out[key] = jax.tree.map(lambda _: NamedSharding(mesh, P(None, dp, None, "model")), leaf)
         elif key == "enc":
             out[key] = NamedSharding(mesh, P(dp, None, None))
         else:  # pragma: no cover

@@ -73,13 +73,13 @@ class Model:
         def init_one(k):
             p, _ = mamba2.init_mamba_layer(
                 k, cfg.d_model, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
-                dtype=cfg.dtype,
+                dtype=cfg.dtype, groups=cfg.ssm_groups,
             )
             return p
 
         _, m_axes = mamba2.init_mamba_layer(
             ks[0], cfg.d_model, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
-            dtype=cfg.dtype,
+            dtype=cfg.dtype, groups=cfg.ssm_groups,
         )
         layers = jax.vmap(init_one)(jax.random.split(ks[1], cfg.n_layers))
         params = {
@@ -105,7 +105,7 @@ class Model:
         L = cfg.n_layers
         if ssm_states is None:
             d_inner, conv_dim = mamba2.mamba_dims(
-                cfg.d_model, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+                cfg.d_model, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
             )
             ssm_states = jnp.zeros(
                 (L, B, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32
@@ -120,10 +120,8 @@ class Model:
                 chunk=cfg.ssm_chunk,
                 ssm_state=ssm_i if decode else None,
                 conv_state=conv_i if decode else None,
-                decode=decode,
+                decode=decode, groups=cfg.ssm_groups,
             )
-            if new_conv is None:
-                new_conv = conv_i
             return hh, (new_ssm, new_conv)
 
         fn = body
@@ -185,18 +183,8 @@ class Model:
             st["pos"] = jnp.full((B,), S, jnp.int32)
             return h, st
         if cfg.family == "hybrid":
-            apps = hybrid.n_attn_applications(cfg)
-            kv = (
-                jnp.zeros((apps, B, max_len, cfg.n_kv_heads, cfg.dh), cfg.dtype),
-                jnp.zeros((apps, B, max_len, cfg.n_kv_heads, cfg.dh), cfg.dtype),
-            )
-            cache_pos = jnp.broadcast_to(
-                jnp.arange(max_len, dtype=jnp.int32)[None], (B, max_len)
-            )
-            h, st = hybrid.forward(
-                cfg, params, tokens, attn_impl=self.attn_impl,
-                kv_caches=kv, cache_positions=cache_pos,
-            )
+            h, st = hybrid.forward(cfg, params, tokens, attn_impl=self.attn_impl,
+                                   max_len=max_len)
             st["pos"] = jnp.full((B,), S, jnp.int32)
             return h, st
         if cfg.family == "audio":
@@ -211,9 +199,10 @@ class Model:
     def decode_step(self, params: Pytree, tokens: jax.Array, state: Dict[str, Any]):
         """One new token per sequence against the cached state.
 
-        For the ``dense``/``moe``/``vlm`` families the token shape chooses
-        the attention, not ``attn_impl``: :func:`transformer.decode` reads
-        each layer's cache in place and writes only the new token's rows."""
+        For the ``dense``/``moe``/``vlm`` and ``hybrid`` families the token
+        shape chooses the attention, not ``attn_impl``:
+        :func:`transformer.decode` and :func:`hybrid.decode` read each cache
+        in place and write only the new token's rows."""
         cfg = self.cfg
         B = tokens.shape[0]
         positions = state["pos"][:, None]
@@ -228,16 +217,7 @@ class Model:
             st["pos"] = state["pos"] + 1
             return h, st
         if cfg.family == "hybrid":
-            kv = state["kv"]
-            max_len = kv[0].shape[2]
-            cache_pos = jnp.broadcast_to(
-                jnp.arange(max_len, dtype=jnp.int32)[None], (B, max_len)
-            )
-            h, st = hybrid.forward(
-                cfg, params, tokens, positions=positions, attn_impl=self.attn_impl,
-                kv_caches=kv, cache_positions=cache_pos,
-                ssm_states=state["ssm"], conv_states=state["conv"], decode=True,
-            )
+            h, st = hybrid.decode(cfg, params, tokens, state["pos"], state)
             st["pos"] = state["pos"] + 1
             return h, st
         if cfg.family == "audio":

@@ -188,18 +188,23 @@ def attention(
     window: Optional[int] = None,
     chunk_attn: Optional[int] = None,
     kv_chunk: int = DEFAULT_CHUNK,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Unified entry point used by every architecture."""
+    """Unified entry point used by every architecture; ``scale`` defaults to
+    ``1/sqrt(Dh)``."""
     if impl == "chunked":
         return attention_chunked(
             q, k, v, q_positions, kv_positions,
-            window=window, chunk_attn=chunk_attn, kv_chunk=kv_chunk,
+            window=window, chunk_attn=chunk_attn, scale=scale, kv_chunk=kv_chunk,
         )
     if impl == "pallas":
         from repro.kernels import ops as kernel_ops
+
+        if scale is not None:
+            raise NotImplementedError("the flash attention kernel scales by 1/sqrt(Dh)")
 
         return kernel_ops.flash_attention(
             q, k, v, q_positions, kv_positions, window=window, chunk_attn=chunk_attn
         )
     bias = causal_mask_bias(q_positions, kv_positions, window=window, chunk=chunk_attn)
-    return attention_xla(q, k, v, bias=bias)
+    return attention_xla(q, k, v, bias=bias, scale=scale)

@@ -27,7 +27,6 @@ from .common import scan as common_scan, rms_norm, trunc_normal
 Pytree = Any
 
 D_CONV = 4  # depthwise causal conv width (mamba2 default)
-N_GROUPS = 1
 
 
 # ---------------------------------------------------------------------------
@@ -35,9 +34,10 @@ N_GROUPS = 1
 # ---------------------------------------------------------------------------
 
 
-def mamba_dims(d_model: int, ssm_heads: int, ssm_head_dim: int, d_state: int):
+def mamba_dims(d_model: int, ssm_heads: int, ssm_head_dim: int, d_state: int, groups: int = 1):
+    """(d_inner, conv width): the conv runs over x and each group's B and C."""
     d_inner = ssm_heads * ssm_head_dim
-    conv_dim = d_inner + 2 * N_GROUPS * d_state
+    conv_dim = d_inner + 2 * groups * d_state
     return d_inner, conv_dim
 
 
@@ -48,11 +48,12 @@ def init_mamba_layer(
     ssm_head_dim: int,
     d_state: int,
     dtype=jnp.bfloat16,
+    groups: int = 1,
 ) -> Tuple[Dict[str, jax.Array], Dict[str, Tuple[str, ...]]]:
     H, P, N = ssm_heads, ssm_head_dim, d_state
-    d_inner, conv_dim = mamba_dims(d_model, H, P, N)
+    d_inner, conv_dim = mamba_dims(d_model, H, P, N, groups)
     ks = jax.random.split(key, 4)
-    proj_out = 2 * d_inner + 2 * N_GROUPS * N + H  # z, x, B, C, dt
+    proj_out = 2 * d_inner + 2 * groups * N + H  # z, x, B, C, dt
     params = {
         "in_proj": trunc_normal(ks[0], (d_model, proj_out), std=1.0 / math.sqrt(d_model), dtype=dtype),
         "conv_w": trunc_normal(ks[1], (D_CONV, conv_dim), std=0.2, dtype=dtype),
@@ -81,6 +82,9 @@ def init_mamba_layer(
 # ---------------------------------------------------------------------------
 # SSD core
 # ---------------------------------------------------------------------------
+#
+# B and C come in ``G`` groups; head ``n`` of ``H`` reads group
+# ``n // (H // G)``.  Heads are split as (G, H // G) wherever they meet B or C.
 
 
 def _segsum(x: jax.Array) -> jax.Array:
@@ -103,7 +107,7 @@ def ssd_chunked(
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns (y (B,S,H,P), final state (B,H,P,N))."""
     B, S, H, P = x.shape
-    N = bm.shape[-1]
+    G, N = bm.shape[-2:]
     nc = -(-S // chunk)
     pad = nc * chunk - S
     if pad:
@@ -113,23 +117,26 @@ def ssd_chunked(
         cm = jnp.pad(cm, ((0, 0), (0, pad), (0, 0), (0, 0)))
 
     Q = chunk
-    xf = x.astype(jnp.float32).reshape(B, nc, Q, H, P)
+    xf = x.astype(jnp.float32).reshape(B, nc, Q, G, H // G, P)
     dtf = dt.astype(jnp.float32).reshape(B, nc, Q, H)
-    bf = bm.astype(jnp.float32).reshape(B, nc, Q, N_GROUPS, N)[..., 0, :]  # (B,nc,Q,N)
-    cf = cm.astype(jnp.float32).reshape(B, nc, Q, N_GROUPS, N)[..., 0, :]
+    bf = bm.astype(jnp.float32).reshape(B, nc, Q, G, N)
+    cf = cm.astype(jnp.float32).reshape(B, nc, Q, G, N)
 
     da = dtf * a[None, None, None, :]  # (B, nc, Q, H) — negative
     da_cum = jnp.cumsum(da, axis=2)  # within chunk
     da_total = da_cum[:, :, -1:, :]  # (B, nc, 1, H)
+    grouped = lambda t: t.reshape(t.shape[:3] + (G, H // G))  # (B, nc, Q, G, H/G)
 
     # ---- intra-chunk (quadratic dual form) ---------------------------------
     L = jnp.exp(_segsum(da.transpose(0, 1, 3, 2)))  # (B, nc, H, Q, Q)
-    scores = jnp.einsum("bcqn,bckn->bcqk", cf, bf)  # (B, nc, Q, Q)
-    y_intra = jnp.einsum("bchqk,bcqk,bckh,bckhp->bcqhp", L, scores, dtf, xf)
+    L = L.reshape(B, nc, G, H // G, Q, Q)
+    scores = jnp.einsum("bcqgn,bckgn->bcgqk", cf, bf)  # (B, nc, G, Q, Q)
+    y_intra = jnp.einsum("bcghqk,bcgqk,bckgh,bckghp->bcqghp", L, scores, grouped(dtf), xf)
 
     # ---- chunk states ------------------------------------------------------
     decay_to_end = jnp.exp(da_total - da_cum)  # (B, nc, Q, H)
-    states = jnp.einsum("bcqn,bcqh,bcqhp->bchpn", bf, dtf * decay_to_end, xf)
+    states = jnp.einsum("bcqgn,bcqgh,bcqghp->bcghpn", bf, grouped(dtf * decay_to_end), xf)
+    states = states.reshape(B, nc, H, P, N)
 
     # ---- inter-chunk recurrence -------------------------------------------
     chunk_decay = jnp.exp(da_total[:, :, 0, :])  # (B, nc, H)
@@ -145,11 +152,11 @@ def ssd_chunked(
         init.astype(jnp.float32),
         (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)),
     )
-    h_prevs = h_prevs.transpose(1, 0, 2, 3, 4)  # (B, nc, H, P, N)
+    h_prevs = h_prevs.transpose(1, 0, 2, 3, 4).reshape(B, nc, G, H // G, P, N)
 
     decay_from_start = jnp.exp(da_cum)  # (B, nc, Q, H)
     y_inter = jnp.einsum(
-        "bcqn,bcqh,bchpn->bcqhp", cf, decay_from_start, h_prevs
+        "bcqgn,bcqgh,bcghpn->bcqghp", cf, grouped(decay_from_start), h_prevs
     )
 
     y = (y_intra + y_inter).reshape(B, nc * Q, H, P)[:, :S]
@@ -160,14 +167,22 @@ def ssd_decode_step(
     x: jax.Array,   # (B, H, P)
     dt: jax.Array,  # (B, H)
     a: jax.Array,   # (H,)
-    bm: jax.Array,  # (B, N)
-    cm: jax.Array,  # (B, N)
+    bm: jax.Array,  # (B, G, N)
+    cm: jax.Array,  # (B, G, N)
     h: jax.Array,   # (B, H, P, N) fp32
 ) -> Tuple[jax.Array, jax.Array]:
-    da = jnp.exp(dt.astype(jnp.float32) * a[None, :])  # (B, H)
-    dbx = jnp.einsum("bh,bn,bhp->bhpn", dt.astype(jnp.float32), bm.astype(jnp.float32), x.astype(jnp.float32))
+    """One step of the recurrence.  B and C are repeated to the heads (a
+    few kilobytes), so the state keeps its own shape: splitting its head
+    axis into groups made the compiler store it in a tiling that moved
+    several times its bytes."""
+    heads_per_group = x.shape[1] // bm.shape[1]
+    bh = jnp.repeat(bm.astype(jnp.float32), heads_per_group, axis=1)  # (B, H, N)
+    ch = jnp.repeat(cm.astype(jnp.float32), heads_per_group, axis=1)
+    dt = dt.astype(jnp.float32)
+    da = jnp.exp(dt * a[None, :])  # (B, H)
+    dbx = (dt[:, :, None] * x.astype(jnp.float32))[..., None] * bh[:, :, None, :]
     h_new = h * da[:, :, None, None] + dbx
-    y = jnp.einsum("bn,bhpn->bhp", cm.astype(jnp.float32), h_new)
+    y = jnp.sum(h_new * ch[:, :, None, :], axis=-1)  # (B, H, P)
     return y, h_new
 
 
@@ -197,49 +212,60 @@ def mamba_layer(
     ssm_state: Optional[jax.Array] = None,   # (B,H,P,N) for decode
     conv_state: Optional[jax.Array] = None,  # (B, D_CONV-1, conv_dim)
     decode: bool = False,
-) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
-    """Returns (h_out, new_ssm_state, new_conv_state)."""
+    groups: int = 1,
+    extra: Optional[jax.Array] = None,  # (B, S, D), added to the input, not the residual
+    eps: float = 1e-6,  # of both norms
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Returns (h_out, new_ssm_state, new_conv_state).
+
+    ``h + Mamba2(RMSNorm(h + extra))``: the norm, projections, conv and
+    gated norm are the ``mamba`` scope, the scan the ``ssd`` scope.  The
+    gated output is normalized over each group's ``d_inner / groups``
+    channels.  The new conv state is the last ``D_CONV - 1`` conv inputs,
+    zeros before the first."""
     B, S, D = h.shape
-    H, P, N = ssm_heads, ssm_head_dim, d_state
-    d_inner, conv_dim = mamba_dims(D, H, P, N)
+    H, P, N, G = ssm_heads, ssm_head_dim, d_state, groups
+    d_inner, conv_dim = mamba_dims(D, H, P, N, G)
 
-    res = h
-    x = rms_norm(h, lp["ln"])
-    proj = x @ lp["in_proj"]  # (B, S, 2*d_inner + 2N + H)
-    z, xbc, dt_raw = jnp.split(proj, [d_inner, d_inner + conv_dim], axis=-1)
+    with jax.named_scope(scopes.MAMBA):
+        x = rms_norm(h if extra is None else h + extra, lp["ln"], eps)
+        proj = x @ lp["in_proj"]  # (B, S, 2*d_inner + 2GN + H)
+        z, xbc, dt_raw = jnp.split(proj, [d_inner, d_inner + conv_dim], axis=-1)
 
-    if decode:
-        assert conv_state is not None
-        window = jnp.concatenate([conv_state.astype(xbc.dtype), xbc], axis=1)
-        new_conv_state = window[:, 1:].astype(jnp.bfloat16)
-        xbc_c = (
-            jnp.einsum("bkc,kc->bc", window, lp["conv_w"]) + lp["conv_b"]
-        )[:, None, :]
-    else:
-        xbc_c = _causal_conv(xbc, lp["conv_w"], lp["conv_b"])
-        new_conv_state = xbc[:, -(D_CONV - 1):, :].astype(jnp.bfloat16) if S >= D_CONV - 1 else None
-    xbc_c = jax.nn.silu(xbc_c)
+        if decode:
+            assert conv_state is not None
+            window = jnp.concatenate([conv_state.astype(xbc.dtype), xbc], axis=1)
+            new_conv_state = window[:, 1:].astype(jnp.bfloat16)
+            xbc_c = (
+                jnp.einsum("bkc,kc->bc", window, lp["conv_w"]) + lp["conv_b"]
+            )[:, None, :]
+        else:
+            xbc_c = _causal_conv(xbc, lp["conv_w"], lp["conv_b"])
+            tail = jnp.pad(xbc, ((0, 0), (max(0, D_CONV - 1 - S), 0), (0, 0)))
+            new_conv_state = tail[:, -(D_CONV - 1):].astype(jnp.bfloat16)
+        xbc_c = jax.nn.silu(xbc_c)
 
-    xs, bm, cm = jnp.split(xbc_c, [d_inner, d_inner + N_GROUPS * N], axis=-1)
-    xs = xs.reshape(B, -1, H, P)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + lp["dt_bias"][None, None, :])
-    a = -jnp.exp(lp["a_log"])  # (H,) negative
+        xs, bm, cm = jnp.split(xbc_c, [d_inner, d_inner + G * N], axis=-1)
+        xs = xs.reshape(B, -1, H, P)
+        bm = bm.reshape(B, -1, G, N)
+        cm = cm.reshape(B, -1, G, N)
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + lp["dt_bias"][None, None, :])
+        a = -jnp.exp(lp["a_log"])  # (H,) negative
 
-    if decode:
-        assert ssm_state is not None
-        with jax.named_scope(scopes.SSD):
+    with jax.named_scope(scopes.SSD):
+        if decode:
+            assert ssm_state is not None
             y, new_state = ssd_decode_step(
                 xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], ssm_state
             )
-        y = y[:, None]  # (B, 1, H, P)
-    else:
-        bm4 = bm.reshape(B, -1, N_GROUPS, N)
-        cm4 = cm.reshape(B, -1, N_GROUPS, N)
-        with jax.named_scope(scopes.SSD):
-            y, new_state = ssd_chunked(xs, dt, a, bm4, cm4, chunk=chunk, h0=ssm_state)
+            y = y[:, None]  # (B, 1, H, P)
+        else:
+            y, new_state = ssd_chunked(xs, dt, a, bm, cm, chunk=chunk, h0=ssm_state)
 
-    y = y + xs.astype(jnp.float32) * lp["d_skip"][None, None, :, None]
-    y = y.reshape(B, -1, d_inner).astype(h.dtype)
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), lp["norm"])
-    out = res + (y @ lp["out_proj"]).astype(h.dtype)
+    with jax.named_scope(scopes.MAMBA):
+        y = y + xs.astype(jnp.float32) * lp["d_skip"][None, None, :, None]
+        y = y.reshape(B, -1, d_inner).astype(h.dtype)
+        y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+        y = rms_norm(y.reshape(B, -1, G, d_inner // G), lp["norm"].reshape(G, -1), eps)
+        out = h + (y.reshape(B, -1, d_inner) @ lp["out_proj"]).astype(h.dtype)
     return out, new_state, new_conv_state

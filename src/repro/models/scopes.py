@@ -24,7 +24,11 @@ ATTENTION = "attention"
 MLP = "mlp"
 #: the vocabulary projection of the last hidden state
 LM_HEAD = "lm_head"
-#: the Mamba2 state-space scan, chunked (prefill) or one step (decode)
+#: the Mamba2 state-space scan, chunked (prefill) or one step (decode), and
+#: in the hybrid's decode the layer's state read and written in place
 SSD = "ssd"
+#: the rest of a Mamba2 layer: its norm, in_proj, conv (with the conv state),
+#: gated norm and out_proj
+MAMBA = "mamba"
 
-ALL = (QKV, KV_WRITE, KV_CARRY, ATTENTION, MLP, LM_HEAD, SSD)
+ALL = (QKV, KV_WRITE, KV_CARRY, ATTENTION, MLP, LM_HEAD, SSD, MAMBA)

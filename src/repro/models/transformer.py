@@ -77,7 +77,13 @@ class ModelConfig:
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_chunk: int = 256
-    attn_period: int = 0             # hybrid: shared attn block every k layers
+    ssm_groups: int = 1              # B/C groups; head n reads group n // (H/G)
+    # hybrid (Zamba2): the layers that a shared attention block feeds, the
+    # number of shared blocks (used in turn), and each application's rank of
+    # the MLP adapter
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0
     dtype: Any = jnp.bfloat16
     # notes for DESIGN.md §Arch-applicability
     notes: str = ""
@@ -497,22 +503,31 @@ def decode(
         return decode_block(cfg, h, lp, kind, positions[:, None], ck, cv)
 
     xs = (params["layers"], cfg.layer_kinds(), jnp.arange(cfg.n_layers))
-    h, (k_new, v_new) = common_scan(scan_body, h, xs)  # (L, B, 1, Hkv, Dh) x2
+    h, rows = common_scan(scan_body, h, xs)  # (L, B, 1, Hkv, Dh) x2
+    new_caches = write_token_rows(kv_caches, rows, positions)
+    h = rms_norm(h, params["final_ln"])
+    return h, new_caches
 
+
+def write_token_rows(
+    caches: Tuple[jax.Array, jax.Array],  # (L, B, Skv, Hkv, Dh) x2
+    rows: Tuple[jax.Array, jax.Array],  # (L, B, 1, Hkv, Dh) x2
+    positions: jax.Array,  # (B,)
+) -> Tuple[jax.Array, jax.Array]:
+    """Each slot's new k and v rows written at its position in every layer,
+    one ``dynamic_update_slice`` per slot and cache: in place when the
+    caches are donated."""
     with jax.named_scope(scopes.KV_WRITE):
         def write(b, caches):
             at = (0, b, positions[b], 0, 0)
             return tuple(
                 jax.lax.dynamic_update_slice(c, jax.lax.dynamic_slice_in_dim(new, b, 1, 1), at)
-                for c, new in zip(caches, (k_new, v_new))
+                for c, new in zip(caches, rows)
             )
 
         # unrolled: for caches with a head size of 128 the TPU compiler
         # fails (an internal RET_CHECK) on a rolled loop here
-        new_caches = jax.lax.fori_loop(0, B, write, (ck_all, cv_all), unroll=True)
-
-    h = rms_norm(h, params["final_ln"])
-    return h, new_caches
+        return jax.lax.fori_loop(0, positions.shape[0], write, tuple(caches), unroll=True)
 
 
 def lm_head(cfg: ModelConfig, params: Pytree, h: jax.Array) -> jax.Array:

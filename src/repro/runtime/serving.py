@@ -8,7 +8,10 @@ A small but real serving loop:
   KV cache at that slot;
 * greedy or temperature sampling;
 * the decode step is a single jitted function over the cache pytree — this
-  is the ``serve_step`` the decode/long-context dry-run cells lower.
+  is the ``serve_step`` the decode/long-context dry-run cells lower;
+* with greedy sampling and no EOS the next decode step is queued on the
+  device before the host waits for this one's tokens, so the host's
+  bookkeeping overlaps the device's work.
 """
 
 from __future__ import annotations
@@ -33,7 +36,7 @@ class ServeConfig:
     batch_slots: int = 8
     max_len: int = 512
     max_new_tokens: int = 32
-    eos: int = 0
+    eos: int = 0  # negative: no EOS token, a request ends at max_new_tokens
     temperature: float = 0.0
     seed: int = 0
 
@@ -65,6 +68,9 @@ class Server:
         self._decode = jax.jit(self._decode_step, donate_argnums=(2,))
         self._insert = jax.jit(self._insert_slot, donate_argnums=(0,))
         self._prefill = jax.jit(self._prefill_fn)
+        # (B, V) logits -> (B, 1) greedy tokens, the decode step's input
+        self._greedy = jax.jit(lambda logits: jnp.argmax(logits, -1).astype(jnp.int32)[:, None])
+        self._greedy_of = (None, None)  # the last logits and their greedy tokens
 
     # -- jitted steps -----------------------------------------------------------
 
@@ -78,9 +84,18 @@ class Server:
         logits = self.model.logits(params, h[:, -1:])
         return logits[:, 0], new_state
 
+    def _tokens(self, logits: jax.Array) -> jax.Array:
+        """The greedy tokens of ``logits`` on the device, taken once per
+        logits array.  The device runs programs in the order they are
+        queued, so an argmax taken again after the next step is queued would
+        wait for that step too."""
+        if self._greedy_of[0] is not logits:
+            self._greedy_of = (logits, self._greedy(logits))
+        return self._greedy_of[1]
+
     def _sample(self, logits: jax.Array, rng: np.random.Generator) -> np.ndarray:
         if self.cfg.temperature <= 0.0:
-            return np.asarray(jnp.argmax(logits, axis=-1))
+            return np.asarray(self._tokens(logits))[:, 0]
         probs = np.asarray(jax.nn.softmax(logits / self.cfg.temperature, axis=-1))
         return np.array(
             [rng.choice(probs.shape[-1], p=probs[i]) for i in range(probs.shape[0])]
@@ -101,7 +116,15 @@ class Server:
         ``serve.sample`` and ``serve.insert``; and a ``serve.step`` per
         decode step (``step``, ``active`` slots, ``refills`` made after it),
         holding ``serve.decode``, ``serve.sample`` (where the host waits for
-        the device's result) and the step's refills."""
+        the device's result) and the step's refills.  A step that queues the
+        next one ahead holds that step's ``serve.decode`` instead of its own.
+
+        Greedy and without EOS, a decode step's input is the last step's
+        tokens, which stay on the device, and a request ends at its count
+        of tokens, which the host knows before the step runs.  So where no
+        request ends with this step, the next is queued before the host
+        waits for this one's tokens: the device runs the steps back to back
+        and the host's bookkeeping overlaps them."""
         cfg = self.cfg
         rng = np.random.default_rng(cfg.seed)
         pending = queue.SimpleQueue()
@@ -152,14 +175,25 @@ class Server:
         for slot in range(cfg.batch_slots):
             fill_slot(slot)
 
+        run_ahead = cfg.temperature <= 0.0 and cfg.eos < 0
+        queued = None  # logits of a step queued during the last step
         step = 0
         while any(r is not None for r in slot_req):
             active = sum(r is not None for r in slot_req)
             with obs.span("serve.step", step=step, active=active) as step_span:
-                with obs.span("serve.decode"):
-                    logits, state = self._decode(
-                        self.params, jnp.asarray(next_tokens)[:, None], state
-                    )
+                if queued is None:
+                    with obs.span("serve.decode"):
+                        logits, state = self._decode(
+                            self.params, jnp.asarray(next_tokens[:, None]), state
+                        )
+                else:
+                    logits, queued = queued, None
+                if run_ahead and all(
+                    req is None or len(slot_tokens[slot]) + 1 < cfg.max_new_tokens
+                    for slot, req in enumerate(slot_req)
+                ):
+                    with obs.span("serve.decode"):
+                        queued, state = self._decode(self.params, self._tokens(logits), state)
                 with obs.span("serve.sample"):
                     sampled = self._sample(logits, rng)
                 refills = 0

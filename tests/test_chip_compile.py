@@ -137,3 +137,49 @@ def test_decode_step_compiles_for_gqa_at_head_size_128(one_chip):
     where a rolled loop of it hit an internal error of the TPU compiler."""
     compiled = _compile_decode_step(one_chip, get_config("qwen2_7b"), 4, 256)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_zamba2_cell_compiles_and_fits(one_chip):
+    """The zamba2_7b serving cell (27 layers at published widths, 24 slots x
+    2048) on one described v5e.  Its decode step holds the weights, the
+    state and its temporaries in 15.75 GiB, reads each application's cache
+    where it is stored and carries each segment's SSM state in the layout
+    it is stored in: no copy, select, scatter or transpose yields an
+    application's cache, the stacked caches or a segment's SSM state.  A
+    1920-token prefill fits beside the weights and the batch state the
+    server holds while it runs."""
+    from chipbench.kinds.serve_zamba2 import program_config as zamba2_config
+    from repro.models import hybrid
+
+    cell = Cell.load("zamba2_7b.decode_heavy_24")
+    cfg = zamba2_config(cell.config)
+    slots, max_len = int(cell.config["serve"]["slots"]), int(cell.config["serve"]["max_len"])
+    hbm = 15.75 * 2**30
+    compiled = _compile_decode_step(one_chip, cfg, slots, max_len)
+    mem = compiled.memory_analysis()
+    state_bytes = mem.output_size_in_bytes  # the new state, aliased to the donated one
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+            - mem.alias_size_in_bytes)
+    assert held < hbm, held / 2**30
+
+    cache = (slots, max_len, cfg.n_kv_heads, cfg.dh)
+    apps = len(cfg.hybrid_layer_ids)
+    moved_shapes = {cache, (1,) + cache, (apps,) + cache, (cfg.n_layers, slots, cfg.ssm_heads,
+                                                          cfg.ssm_head_dim, cfg.ssm_state)}
+    moved_shapes |= {(hi - lo, slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+                     for _, lo, hi in hybrid.segments(cfg)}
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) in ("copy", "select", "scatter", "transpose"):
+            if tuple(int(d) for d in m.group(2).split(",") if d) in moved_shapes:
+                moved.append(line.strip()[:160])
+    assert not moved, moved
+
+    params = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
+                          Model(cfg).abstract_init()[0])
+    server = Server(cfg, ServeConfig(batch_slots=slots, max_len=max_len), params)
+    prefill = server._prefill.lower(params, _spec(one_chip, (1, 1920), jnp.int32)).compile()
+    pm = prefill.memory_analysis()
+    assert (pm.argument_size_in_bytes + pm.output_size_in_bytes + pm.temp_size_in_bytes
+            + state_bytes) < hbm

@@ -34,14 +34,19 @@ def _code(text: str) -> str:
     return re.sub(r"(%?[A-Za-z_][\w-]*)\.\d+\b", r"\1", "\n".join(lines))
 
 
-def test_decode_step_holds_every_transformer_scope():
-    found = _scopes_in(_decode_text(reduced_config("stablelm_3b")))
-    assert found == {scopes.QKV, scopes.KV_WRITE, scopes.KV_CARRY, scopes.ATTENTION,
-                     scopes.MLP, scopes.LM_HEAD}
+@pytest.mark.parametrize("arch,expected", [
+    ("stablelm_3b", {scopes.QKV, scopes.KV_WRITE, scopes.KV_CARRY, scopes.ATTENTION,
+                     scopes.MLP, scopes.LM_HEAD}),
+    ("zamba2_7b", {scopes.QKV, scopes.KV_WRITE, scopes.ATTENTION, scopes.MLP, scopes.LM_HEAD,
+                   scopes.SSD, scopes.MAMBA}),
+], ids=["transformer", "hybrid"])
+def test_decode_step_holds_every_transformer_scope(arch, expected):
+    assert _scopes_in(_decode_text(reduced_config(arch))) == expected
 
 
-def test_scopes_leave_the_compiled_decode_step_unchanged(monkeypatch):
-    cfg = reduced_config("stablelm_3b")
+@pytest.mark.parametrize("arch", ["stablelm_3b", "zamba2_7b"])
+def test_scopes_leave_the_compiled_decode_step_unchanged(monkeypatch, arch):
+    cfg = reduced_config(arch)
     scoped = _decode_text(cfg)
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
     plain = _decode_text(cfg)
@@ -63,4 +68,4 @@ def test_mamba2_block_holds_the_ssd_scope(decode):
         fn = jax.jit(lambda p, t: model.prefill(p, {"tokens": t}, MAX_LEN))
         args = (params, tokens)
     text = fn.lower(*args).compile().as_text()
-    assert scopes.SSD in _scopes_in(text)
+    assert {scopes.SSD, scopes.MAMBA} <= _scopes_in(text)

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_config, param_count, reduced_config, shape_cells
-from repro.models import Model, transformer
+from repro.models import Model, hybrid, transformer
 from repro.models.attention import attention_chunked, attention_xla
 from repro.runtime.serving import Server
 
@@ -71,7 +71,8 @@ def test_arch_smoke_prefill_decode(arch):
     assert int(state2["pos"][0]) == int(state["pos"][0]) + 1
 
 
-@pytest.mark.parametrize("arch", ["stablelm_3b", "gemma3_1b", "mamba2_370m", "zamba2_2_7b"])
+@pytest.mark.parametrize("arch", ["stablelm_3b", "gemma3_1b", "mamba2_370m", "zamba2_2_7b",
+                                  "zamba2_7b"])
 def test_decode_consistency_with_forward(arch):
     """KV-cache / SSM-state decode must match the full forward (fp32, with
     fp32 caches isolated from quantization by tolerance)."""
@@ -85,8 +86,6 @@ def test_decode_consistency_with_forward(arch):
     elif cfg.family == "ssm":
         h_full, _ = model._ssm_forward(params, toks)
     else:
-        from repro.models import hybrid
-
         h_full, _ = hybrid.forward(cfg, params, toks, attn_impl="xla")
     _, state = model.prefill(params, {"tokens": toks[:, :S]}, max_len=S + 4)
     h_dec, _ = model.decode_step(params, toks[:, S : S + 1], state)
@@ -96,14 +95,16 @@ def test_decode_consistency_with_forward(arch):
 
 @pytest.mark.parametrize("lengths", [(5, 21, 32, 38), (0, 21)], ids=["ragged", "empty_slot"])
 @pytest.mark.parametrize(
-    "arch", ["stablelm_3b", "qwen2_7b", "gemma3_1b", "llama4_scout_17b_a16e"]
+    "arch",
+    ["stablelm_3b", "qwen2_7b", "gemma3_1b", "llama4_scout_17b_a16e", "zamba2_2_7b", "zamba2_7b"],
 )
 def test_decode_at_ragged_positions_matches_forward(arch, lengths):
     """Rows prefilled to different lengths and placed into one batch state,
     as ``Server`` places them, each decode one token that matches the full
     forward over the row's own sequence.  The lengths cross gemma3's window
-    (16) and llama4's attention chunk (32); a slot never filled sits at
-    position 0 and attends to its token alone."""
+    (16), llama4's attention chunk (32) and the hybrids' SSD chunk (16); a
+    slot never filled sits at position 0 and attends to its token alone,
+    from zero SSM and conv states."""
     cfg = dataclasses.replace(reduced_config(arch), dtype=jnp.float32)
     model = Model(cfg)
     params, _ = model.init(jax.random.PRNGKey(0))
@@ -132,8 +133,9 @@ def test_decode_at_ragged_positions_matches_forward(arch, lengths):
         assert bool(jnp.all(jnp.isfinite(h)))
         h_dec.append(h[:, 0])
     assert [int(p) for p in state["pos"]] == [n + 2 for n in lengths]
+    forward = hybrid.forward if cfg.family == "hybrid" else transformer.forward
     for slot, n in enumerate(lengths):
-        h_full, _ = transformer.forward(cfg, params, toks[slot:slot + 1, :n + 2], attn_impl="xla")
+        h_full, _ = forward(cfg, params, toks[slot:slot + 1, :n + 2], attn_impl="xla")
         for step in range(2):
             err = float(jnp.abs(h_dec[step][slot] - h_full[0, n + step]).max())
             assert err < 5e-2, (slot, n, step, err)  # bf16 cache quantization bound
@@ -152,6 +154,7 @@ def test_full_configs_match_assignment():
         "whisper_large_v3": (32, 1280, 20, 20, 5120, 51866),
         "mamba2_370m": (48, 1024, 0, 0, 0, 50280),
         "zamba2_2_7b": (54, 2560, 32, 32, 10240, 32000),
+        "zamba2_7b": (81, 3584, 32, 32, 14336, 32000),
     }
     for arch, (L, D, Hq, Hkv, F, V) in spec.items():
         cfg = get_config(arch)
@@ -164,6 +167,9 @@ def test_full_configs_match_assignment():
     assert get_config("llama4_scout_17b_a16e").moe.top_k == 1
     assert get_config("mamba2_370m").ssm_state == 128
     assert get_config("zamba2_2_7b").ssm_state == 64
+    assert get_config("zamba2_2_7b").hybrid_layer_ids == (6, 12, 18, 24, 30, 36, 42, 48)
+    assert get_config("zamba2_2_7b").dh == 2 * 2560 // 32
+    assert get_config("zamba2_7b").ssm_groups == 2
 
 
 def test_shape_cells_cover_assignment():

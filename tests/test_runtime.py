@@ -184,6 +184,40 @@ def test_server_continuous_batching():
         assert 1 <= len(c.tokens) <= 4
 
 
+def test_server_queues_greedy_steps_ahead_with_the_same_tokens():
+    # Without EOS the next decode step is queued before the host reads this
+    # one's tokens; an EOS that no token can be makes the host wait for every
+    # step.  Both give the same tokens from the same number of steps.
+    cfg = reduced_config("stablelm_3b")
+    params, _ = Model(cfg).init(jax.random.PRNGKey(0))
+    reqs = [Request(uid=i, prompt=np.arange(1, 5 + i, dtype=np.int32)) for i in range(5)]
+    tokens, calls = {}, {}
+    for eos in (-1, cfg.vocab):
+        server = Server(cfg, ServeConfig(batch_slots=2, max_len=32, max_new_tokens=4, eos=eos),
+                        params)
+        order = calls[eos] = []
+        decode, sample, greedy = server._decode, server._sample, server._greedy
+        server._decode = lambda *a, f=decode, o=order: o.append("decode") or f(*a)
+        server._sample = lambda *a, f=sample, o=order: o.append("sample") or f(*a)
+        server._greedy = lambda *a, f=greedy, o=order: o.append("greedy") or f(*a)
+        tokens[eos] = [(c.uid, c.tokens) for c in server.serve(reqs)]
+    assert tokens[-1] == tokens[cfg.vocab]
+    assert all(len(t) == 4 for _, t in tokens[-1])
+    assert calls[-1].count("decode") == calls[cfg.vocab].count("decode") > 0
+    # one argmax per prefill and per step: the host reads the tokens that
+    # were taken before the next step was queued
+    for order in calls.values():
+        assert order.count("greedy") == order.count("decode") + len(reqs)
+
+    def pairs(order):
+        steps = [c for c in order if c != "greedy"]
+        return sum(a == b == "decode" for a, b in zip(steps, steps[1:]))
+
+    assert pairs(calls[cfg.vocab]) == 0
+    # each request's first decode step queues its second
+    assert pairs(calls[-1]) >= 3
+
+
 @pytest.fixture
 def telemetry():
     """The process-wide telemetry, clean and enabled; restored afterwards."""
