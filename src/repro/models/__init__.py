@@ -233,6 +233,22 @@ class Model:
             return h, {"kv": new_kv, "enc": state["enc"], "pos": state["pos"] + 1}
         raise ValueError(cfg.family)
 
+    def decode_kv_blocks(self, positions, max_len: int) -> Optional[Tuple[int, int]]:
+        """(blocks copied, blocks stored) of each KV cache entry (a layer's,
+        or an application's of a shared block) when a decode step of slots
+        at ``positions`` reads ``max_len``-position caches through the
+        decode-attention kernel, counted on the host for full attention;
+        None for a family whose decode step does not read a cache that way."""
+        cfg = self.cfg
+        if cfg.family not in ("dense", "moe", "vlm", "hybrid"):
+            return None
+        from repro.kernels import decode_attention
+
+        cache_dtype = cfg.dtype if cfg.family == "hybrid" else jnp.bfloat16  # as prefill makes it
+        _, block = decode_attention.block_sizes(cfg.n_kv_heads, cfg.dh, max_len,
+                                                jnp.dtype(cache_dtype).itemsize)
+        return decode_attention.read_share(positions, max_len, block)
+
     def logits(self, params: Pytree, h: jax.Array) -> jax.Array:
         cfg = self.cfg
         with jax.named_scope(scopes.LM_HEAD):

@@ -14,7 +14,10 @@ All paths share the GQA head-grouping and mask conventions and are tested
 allclose against each other.
 
 Decoding one token per sequence against a KV cache takes
-:func:`attention_decode` whatever the ``impl``: the shape chooses it.
+:func:`attention_decode_at` whatever the ``impl``: the shape chooses it, and
+the platform chooses between the Pallas kernel
+(:mod:`repro.kernels.decode_attention`) on a TPU and :func:`attention_decode`
+elsewhere.
 """
 
 from __future__ import annotations
@@ -25,6 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from . import scopes
 from .common import scan as common_scan, NEG_INF, causal_mask_bias
 
 DEFAULT_CHUNK = 1024
@@ -174,6 +178,84 @@ def attention_decode(
     vf = v_cache[:, :, :, None, :].astype(jnp.float32)
     acc = jnp.sum(p[..., None] * vf, axis=1)  # (B, Hkv, G, Dh)
     acc = acc + p_own[..., None] * v[:, 0, :, None, :].astype(jnp.float32)
+    out = acc / l[..., None]
+    return out.reshape(b, 1, hq, dh).astype(q.dtype)
+
+
+def attention_decode_at(
+    q: jax.Array,  # (B, 1, Hq, Dh): one query token per sequence
+    k: jax.Array,  # (B, 1, Hkv, Dh): that token's own key
+    v: jax.Array,  # (B, 1, Hkv, Dh)
+    k_all: jax.Array,  # (N, B, Skv, Hkv, Dh): stacked caches
+    v_all: jax.Array,  # (N, B, Skv, Hkv, Dh)
+    index: jax.Array,  # () int: the entry of the stack to attend to
+    positions: jax.Array,  # (B,) each token's position
+    window: Optional[jax.Array] = None,
+    chunk_attn: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """:func:`attention_decode` against entry ``index`` of stacked caches:
+    on a TPU :func:`attention_decode_kernel`; elsewhere the entry's slices
+    (scope ``kv_carry``) go to :func:`attention_decode`.  -> (B, 1, Hq, Dh)"""
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+
+    def reference():
+        with jax.named_scope(scopes.KV_CARRY):
+            k_cache, v_cache = k_all[index], v_all[index]
+        return attention_decode(q, k, v, k_cache, v_cache, positions, window=window,
+                                chunk_attn=chunk_attn, scale=scale)
+
+    def kernel():
+        return attention_decode_kernel(q, k, v, k_all, v_all, index, positions, window=window,
+                                       chunk_attn=chunk_attn, scale=scale)
+
+    return jax.lax.platform_dependent(tpu=kernel, default=reference)
+
+
+def attention_decode_kernel(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    k_all: jax.Array,
+    v_all: jax.Array,
+    index: jax.Array,
+    positions: jax.Array,
+    window: Optional[jax.Array] = None,
+    chunk_attn: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """:func:`attention_decode_at` by the Pallas kernel
+    (:mod:`repro.kernels.decode_attention`), which takes the whole stack,
+    read where it is stored: positions are the minor dimension of the
+    caches' stored layout, so the kernel's view of them, ``(N, B, Hkv, Dh,
+    Skv)``, is those bytes.  It reads each slot's positions in ``[lo,
+    min(position, Skv))`` only, ``lo`` from the window and chunk masks; the
+    token's own key and value are merged here in the same float32 softmax.
+    ``interpret`` runs the kernel in Pallas's interpreter."""
+    from repro.kernels.decode_attention import decode_attention
+
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    b, _, hq, dh = q.shape
+    hkv = k.shape[2]
+    hi = jnp.minimum(positions, k_all.shape[2])
+    lo = jnp.zeros_like(positions)
+    if window is not None:
+        lo = jnp.maximum(lo, positions - window + 1)
+    if chunk_attn is not None:
+        lo = jnp.maximum(lo, positions // chunk_attn * chunk_attn)
+    stored = lambda c: jnp.moveaxis(c, 2, -1)
+    m, l, acc = decode_attention(q[:, 0], stored(k_all), stored(v_all), index,
+                                 jnp.minimum(lo, hi), hi, scale=scale, interpret=interpret)
+
+    qf = q.reshape(b, hkv, hq // hkv, dh).astype(jnp.float32)
+    own = jnp.sum(qf * k[:, 0, :, None, :].astype(jnp.float32), axis=-1) * scale
+    m_cache = m.reshape(own.shape)
+    m = jnp.maximum(m_cache, own)  # (B, Hkv, G)
+    corr, p_own = jnp.exp(m_cache - m), jnp.exp(own - m)
+    l = l.reshape(own.shape) * corr + p_own
+    acc = (acc.reshape(qf.shape) * corr[..., None]
+           + p_own[..., None] * v[:, 0, :, None, :].astype(jnp.float32))
     out = acc / l[..., None]
     return out.reshape(b, 1, hq, dh).astype(q.dtype)
 

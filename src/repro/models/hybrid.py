@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from . import scopes
-from .attention import attention, attention_decode
+from .attention import attention, attention_decode_at
 from .common import scan as common_scan, apply_rope, dense_init, rms_norm, trunc_normal
 from .mamba2 import D_CONV, init_mamba_layer, mamba_dims, mamba_layer
 from .transformer import ModelConfig, lm_loss, write_token_rows
@@ -298,8 +298,8 @@ def decode(
             q, k, v = _shared_qkv(cfg, bp, h, e, positions[:, None])
             k, v = k.astype(ck_all.dtype), v.astype(cv_all.dtype)
             with jax.named_scope(scopes.ATTENTION):
-                o = attention_decode(q, k, v, ck_all[j], cv_all[j], positions,
-                                     scale=attention_scale(cfg))
+                o = attention_decode_at(q, k, v, ck_all, cv_all, j, positions,
+                                        scale=attention_scale(cfg))
                 o = o.reshape(B, 1, -1) @ bp["wo"]
             t = _shared_mlp(cfg, bp, ap, o)
             rows[0].append(k)

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from . import scopes
-from .attention import attention, attention_decode
+from .attention import attention, attention_decode_at
 from .common import scan as common_scan, apply_mrope, apply_rope, rms_norm, swiglu, trunc_normal
 
 Pytree = Any
@@ -380,22 +380,24 @@ def decode_block(
     lp: Dict[str, jax.Array],
     kind: jax.Array,
     positions: jax.Array,  # (B, 1)
-    ck: jax.Array,  # (B, Skv, Hkv, Dh): this layer's cache, read only
-    cv: jax.Array,
+    caches: Tuple[jax.Array, jax.Array],  # (L, B, Skv, Hkv, Dh) x2, read only
+    i: jax.Array,  # this layer's index into them
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """:func:`block` for one token per sequence against a layer's cache,
-    which it only reads; returns (h, the token's (k, v) in the cache's
-    dtype) for the caller to write."""
+    """:func:`block` for one token per sequence against layer ``i`` of the
+    stacked caches, which it only reads; returns (h, the token's (k, v) in
+    the caches' dtype) for the caller to write."""
+    ck_all, cv_all = caches
     with jax.named_scope(scopes.QKV):
         x = rms_norm(h, lp["ln1"])
         q, k, v = _qkv(x, lp, cfg)
         q = _rope(cfg, q, positions, kind)
-        k = _rope(cfg, k, positions, kind).astype(ck.dtype)
-        v = v.astype(cv.dtype)
+        k = _rope(cfg, k, positions, kind).astype(ck_all.dtype)
+        v = v.astype(cv_all.dtype)
 
     window, chunk = _mask_params(cfg, kind)
     with jax.named_scope(scopes.ATTENTION):
-        o = attention_decode(q, k, v, ck, cv, positions[:, 0], window=window, chunk_attn=chunk)
+        o = attention_decode_at(q, k, v, ck_all, cv_all, i, positions[:, 0], window=window,
+                                chunk_attn=chunk)
         h = h + (o.reshape(h.shape[0], 1, -1) @ lp["wo"]).astype(h.dtype)
     return _mlp(cfg, h, lp), (k, v)
 
@@ -485,7 +487,7 @@ def decode(
 
     The caches are not carried through the layer scan, where the compiler
     copies each layer's slice out and back: each layer reads its slice where
-    it is stored (:func:`attention_decode`), and the layers' new rows come
+    it is stored (:func:`attention_decode_at`), and the layers' new rows come
     out of the scan and are written after it, slot by slot, in place when
     the caches are donated.  One scatter of all the rows would make the
     compiler copy both caches into another layout around it.
@@ -494,13 +496,10 @@ def decode(
     if S != 1:
         raise ValueError(f"decode takes one token per sequence, got {S}")
     h = params["embed"][tokens].astype(cfg.dtype)
-    ck_all, cv_all = kv_caches
 
     def scan_body(h, xs):
         lp, kind, i = xs
-        with jax.named_scope(scopes.KV_CARRY):
-            ck, cv = ck_all[i], cv_all[i]
-        return decode_block(cfg, h, lp, kind, positions[:, None], ck, cv)
+        return decode_block(cfg, h, lp, kind, positions[:, None], kv_caches, i)
 
     xs = (params["layers"], cfg.layer_kinds(), jnp.arange(cfg.n_layers))
     h, rows = common_scan(scan_body, h, xs)  # (L, B, 1, Hkv, Dh) x2

@@ -118,6 +118,11 @@ class Server:
         holding ``serve.decode``, ``serve.sample`` (where the host waits for
         the device's result) and the step's refills.  A step that queues the
         next one ahead holds that step's ``serve.decode`` instead of its own.
+        Where the model's decode reads KV caches through the decode-attention
+        kernel, a ``serve.step`` also holds ``kv_read_share``: the share of
+        each cache entry's stored blocks that the kernel copies for the
+        active slots' positions, whose sums are the counters
+        ``serve.kv_blocks_read`` and ``serve.kv_blocks_stored``.
 
         Greedy and without EOS, a decode step's input is the last step's
         tokens, which stay on the device, and a request ends at its count
@@ -181,6 +186,10 @@ class Server:
         while any(r is not None for r in slot_req):
             active = sum(r is not None for r in slot_req)
             with obs.span("serve.step", step=step, active=active) as step_span:
+                if obs.enabled():
+                    self._count_kv_reads(step_span, [
+                        len(req.prompt) + len(slot_tokens[slot]) - 1
+                        for slot, req in enumerate(slot_req) if req is not None])
                 if queued is None:
                     with obs.span("serve.decode"):
                         logits, state = self._decode(
@@ -215,6 +224,17 @@ class Server:
                 step_span.set(refills=refills)
             step += 1
         return sorted(done, key=lambda c: c.uid)
+
+    def _count_kv_reads(self, step_span, positions: List[int]) -> None:
+        """``kv_read_share`` on ``step_span`` and its counters, for a decode
+        step of slots at ``positions``."""
+        blocks = self.model.decode_kv_blocks(positions, self.cfg.max_len)
+        if blocks is None:
+            return
+        read, stored = blocks
+        step_span.set(kv_read_share=read / stored)
+        obs.metrics().counter("serve.kv_blocks_read").inc(read)
+        obs.metrics().counter("serve.kv_blocks_stored").inc(stored)
 
     # -- slot surgery -------------------------------------------------------------
     # State leaves keyed by their top-level name:

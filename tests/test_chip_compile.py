@@ -107,27 +107,129 @@ def _compile_decode_step(sharding, cfg, slots, max_len):
     return server._decode.lower(params, tokens, state).compile()
 
 
+def _cache_shapes(stack: int, slots: int, max_len: int, hkv: int, dh: int):
+    """A stack of ``stack`` caches, and one entry or slot of it, as the model
+    holds them ``(.., slots, max_len, hkv, dh)`` and as the decode kernel
+    reads them ``(.., slots, hkv, dh, max_len)``."""
+    out = set()
+    for entry in ((slots, max_len, hkv, dh), (slots, hkv, dh, max_len)):
+        out |= {entry, (1,) + entry, (stack,) + entry}
+    return out
+
+
+def _moved(text: str, shapes) -> list:
+    """The instructions that copy, select, scatter or transpose into one of
+    ``shapes``."""
+    moved = []
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) in ("copy", "select", "scatter", "transpose"):
+            if tuple(int(d) for d in m.group(2).split(",") if d) in shapes:
+                moved.append(line.strip()[:160])
+    return moved
+
+
+def _instructions(text: str):
+    """Instruction name -> (computation, opcode, operand names, line), and
+    the name of the entry computation."""
+    out, computation, entry = {}, None, None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            head = re.match(r"^(ENTRY\s+)?%?([^\s(]+).*\{\s*$", line)
+            if head:
+                computation = head.group(2)
+                entry = computation if head.group(1) else entry
+            continue
+        m = re.match(r"^\s+(?:ROOT\s+)?%?([^\s=]+) = (.*)$", line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        if rest.startswith("("):  # a tuple's shape
+            depth = 0
+            for end, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if not depth:
+                    break
+            rest = rest[end + 1:].lstrip()
+        else:
+            rest = rest.split(" ", 1)[1]
+        op = re.match(r"([\w-]+)\(", rest)
+        depth, args = 1, op.end()
+        for end in range(op.end(), len(rest)):
+            depth += (rest[end] == "(") - (rest[end] == ")")
+            if not depth:
+                args = rest[op.end():end]
+                break
+        out[name] = (computation, op.group(1), re.findall(r"%([\w.\-]+)", args), line)
+    return out, entry
+
+
+def _entry_parameter(instructions, entry: str, name: str):
+    """The entry computation's parameter that instruction ``name`` is,
+    through bitcasts and the tuples of the loops it is in; None where some
+    instruction on the way makes a new value."""
+    loops = {}  # loop body -> the tuple a while passes it
+    for computation, op, operands, line in instructions.values():
+        body = re.search(r"\bbody=%?([^\s,]+)", line)
+        if op == "while" and body:
+            loops[body.group(1)] = operands[0]
+    while True:
+        computation, op, operands, line = instructions[name]
+        if op == "bitcast":
+            name = operands[0]
+        elif op == "parameter":
+            return name if computation == entry else None
+        elif op == "get-tuple-element":
+            source = instructions[operands[0]]
+            if source[1] != "parameter" or source[0] not in loops:
+                return None
+            passed = instructions[loops[source[0]]]
+            if passed[1] != "tuple":
+                return None
+            name = passed[2][int(re.search(r"\bindex=(\d+)", line).group(1))]
+        else:
+            return None
+
+
+def _assert_kernel_reads_the_stacked_caches(text: str, stack_shape, kernels: int):
+    """``kernels`` decode-attention custom calls, each in the ``attention``
+    scope, whose cache operands are the step's stacked cache parameters
+    themselves, seen as the kernel reads them: no copy between."""
+    from chipbench.scopes import instruction_scopes
+    from repro.models import scopes
+
+    instructions, entry = _instructions(text)
+    calls = {name: operands for name, (_, op, operands, line) in instructions.items()
+             if op == "custom-call" and "tpu_custom_call" in line and "decode_attention" in line}
+    assert len(calls) == kernels, sorted(calls)
+    in_scope = instruction_scopes(text, scopes.ALL)
+    shape = "bf16[%s]" % ",".join(map(str, stack_shape))
+    for name, operands in calls.items():
+        assert in_scope[name] == scopes.ATTENTION, name
+        caches = [_entry_parameter(instructions, entry, o) for o in operands[-2:]]
+        assert all(c is not None and shape in instructions[c][3] for c in caches), (name, caches)
+        assert len(set(caches)) == 2, caches
+
+
 def test_serving_decode_step_moves_no_cache_sized_data(one_chip):
     """``Server``'s decode step at the stablelm_3b serving cell's sizes (12
-    slots x 2048, full widths) reads each layer's cache slice where it is
-    stored and writes only the token's rows: no copy, select, scatter or
-    transpose yields a layer's slice or the stacked cache, and the step
-    needs under 64 MiB of temporaries beside its arguments."""
+    slots x 2048, full widths) reads each layer's cache where it is stored,
+    through the decode-attention kernel, and writes only the token's rows:
+    the kernel takes the stacked cache parameters themselves, in the
+    ``attention`` scope, and no copy, select, scatter or transpose yields a
+    layer's slice or the stacked cache, as the model holds it or as the
+    kernel reads it.  The step needs under 64 MiB of temporaries beside its
+    arguments."""
     cell = Cell.load("stablelm_3b.decode_heavy")
     cfg = program_config(cell.config)
     slots, max_len = int(cell.config["serve"]["slots"]), int(cell.config["serve"]["max_len"])
     compiled = _compile_decode_step(one_chip, cfg, slots, max_len)
+    text = compiled.as_text()
 
-    slice_dims = (slots, max_len, cfg.n_kv_heads, cfg.dh)
-    cache_sized = {slice_dims, (1,) + slice_dims, (cfg.n_layers,) + slice_dims}
-    moved = []
-    for line in compiled.as_text().splitlines():
-        m = _INSTRUCTION.match(line)
-        if m and m.group(3) in ("copy", "select", "scatter", "transpose"):
-            dims = tuple(int(d) for d in m.group(2).split(",") if d)
-            if dims in cache_sized:
-                moved.append(line.strip()[:160])
+    moved = _moved(text, _cache_shapes(cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.dh))
     assert not moved, moved
+    _assert_kernel_reads_the_stacked_caches(
+        text, (cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.dh), kernels=1)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
@@ -142,10 +244,14 @@ def test_decode_step_compiles_for_gqa_at_head_size_128(one_chip):
 def test_zamba2_cell_compiles_and_fits(one_chip):
     """The zamba2_7b serving cell (27 layers at published widths, 24 slots x
     2048) on one described v5e.  Its decode step holds the weights, the
-    state and its temporaries in 15.75 GiB, reads each application's cache
-    where it is stored and carries each segment's SSM state in the layout
-    it is stored in: no copy, select, scatter or transpose yields an
-    application's cache, the stacked caches or a segment's SSM state.  A
+    state and its temporaries in 15.75 GiB, reads each
+    application's cache where it is stored, through the decode-attention
+    kernel (one custom call an application, in the ``attention`` scope,
+    taking the stacked cache parameters themselves), and carries each
+    segment's SSM state in the layout it is stored in: no copy, select,
+    scatter or transpose yields an application's cache or the stacked
+    caches, as the model holds them or as the kernel reads them, or a
+    segment's SSM state.  A
     1920-token prefill fits beside the weights and the batch state the
     server holds while it runs."""
     from chipbench.kinds.serve_zamba2 import program_config as zamba2_config
@@ -162,19 +268,18 @@ def test_zamba2_cell_compiles_and_fits(one_chip):
             - mem.alias_size_in_bytes)
     assert held < hbm, held / 2**30
 
-    cache = (slots, max_len, cfg.n_kv_heads, cfg.dh)
     apps = len(cfg.hybrid_layer_ids)
-    moved_shapes = {cache, (1,) + cache, (apps,) + cache, (cfg.n_layers, slots, cfg.ssm_heads,
-                                                          cfg.ssm_head_dim, cfg.ssm_state)}
+    moved_shapes = _cache_shapes(apps, slots, max_len, cfg.n_kv_heads, cfg.dh)
+    moved_shapes |= {(cfg.n_layers, slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)}
     moved_shapes |= {(hi - lo, slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
                      for _, lo, hi in hybrid.segments(cfg)}
-    moved = []
-    for line in compiled.as_text().splitlines():
-        m = _INSTRUCTION.match(line)
-        if m and m.group(3) in ("copy", "select", "scatter", "transpose"):
-            if tuple(int(d) for d in m.group(2).split(",") if d) in moved_shapes:
-                moved.append(line.strip()[:160])
+    text = compiled.as_text()
+    moved = _moved(text, moved_shapes)
     assert not moved, moved
+    _assert_kernel_reads_the_stacked_caches(
+        text, (apps, slots, max_len, cfg.n_kv_heads, cfg.dh), kernels=apps)
+    # 399.5 MiB before the kernel, which adds its outputs and the queries' view
+    assert mem.temp_size_in_bytes < 401 * 2**20, mem.temp_size_in_bytes / 2**20
 
     params = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
                           Model(cfg).abstract_init()[0])

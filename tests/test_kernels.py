@@ -196,3 +196,79 @@ def test_ssd_matches_model_scan_path():
     y_model, h_model = ssd_chunked(x, dt, a, bm[:, :, None, :], cm[:, :, None, :], chunk=16)
     np.testing.assert_allclose(y_kernel, y_model, atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(h_kernel, h_model, atol=1e-4, rtol=1e-4)
+
+
+# -- decode attention over a stacked cache ------------------------------------
+
+DECODE_CASES = [
+    # (Hq, Hkv, Dh, max_len, scale, window, chunk)
+    pytest.param(32, 32, 80, 512, None, None, None, id="mha_32x80"),
+    pytest.param(32, 32, 224, 512, 112 ** -0.5, None, None, id="mha_32x224_zamba2_scale"),
+    pytest.param(28, 4, 128, 2048, None, None, None, id="gqa_28_4x128"),
+    pytest.param(32, 32, 80, 512, None, 300, None, id="window_300"),
+    pytest.param(32, 32, 80, 512, None, None, 192, id="chunk_192"),
+]
+
+
+@pytest.mark.parametrize("hq,hkv,dh,max_len,scale,window,chunk", DECODE_CASES)
+def test_decode_attention_matches_xla(hq, hkv, dh, max_len, scale, window, chunk):
+    """The kernel path of the decode attention (interpret mode), its own
+    key and value merged outside, against the XLA ``attention_decode`` on
+    the same entry of a stack of three caches: slots at position 1, either
+    side of a block boundary, on it, and at the last position, in one
+    batch."""
+    from repro.kernels import decode_attention as da
+    from repro.models.attention import attention_decode, attention_decode_kernel
+
+    block = da.block_sizes(hkv, dh, max_len)[1]
+    positions = jnp.array([1, block - 1, block, block + 1, max_len - 1], jnp.int32)
+    b, n, index = positions.shape[0], 3, 1
+    ks = jax.random.split(jax.random.PRNGKey(hq + dh), 5)
+    q = jax.random.normal(ks[0], (b, 1, hq, dh), jnp.float32)
+    k = jax.random.normal(ks[1], (b, 1, hkv, dh), jnp.float32)
+    v = jax.random.normal(ks[2], (b, 1, hkv, dh), jnp.float32)
+    k_all = jax.random.normal(ks[3], (n, b, max_len, hkv, dh), jnp.float32).astype(jnp.bfloat16)
+    v_all = jax.random.normal(ks[4], (n, b, max_len, hkv, dh), jnp.float32).astype(jnp.bfloat16)
+    masks = dict(window=window, chunk_attn=chunk, scale=scale)
+
+    got = attention_decode_kernel(q, k, v, k_all, v_all, jnp.int32(index), positions,
+                                  interpret=True, **masks)
+    want = attention_decode(q, k, v, k_all[index], v_all[index], positions, **masks)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("hkv,dh,max_len", [(32, 80, 2048), (32, 224, 2048), (4, 128, 2048),
+                                            (4, 16, 32), (64, 256, 1024)])
+def test_kv_read_share_counts_the_blocks_the_index_map_visits(hkv, dh, max_len):
+    """The host's count of a decode step's cache blocks equals the copies the
+    kernel's grid issues, one wherever a step's block index differs from the
+    step before it, in every head block; and every live block is copied."""
+    from repro.kernels import decode_attention as da
+
+    heads, block = da.block_sizes(hkv, dh, max_len)
+    n_heads, n_blocks = hkv // heads, max_len // block
+    positions = np.array([0, 1, block - 1, block, block + 1, max_len - 1, max_len, max_len + 7])
+    hi = np.minimum(positions, max_len)
+    lo = np.zeros_like(hi)
+    where = dict(slots=len(hi), n_heads=n_heads, block=block, n_blocks=n_blocks)
+    steps = [tuple(int(x) for x in da.kv_index(i, h, s, lo, hi, **where))
+             for i in range(len(hi)) for h in range(n_heads) for s in range(n_blocks)]
+    copies = sum(1 for prev, cur in zip([None] + steps, steps) if cur != prev)
+    read, stored = da.read_share(positions, max_len, block)
+    assert (n_heads * read, stored) == (copies, n_blocks * len(positions))
+    live = {(i, h, s) for i in range(len(hi)) for h in range(n_heads)
+            for s in range(n_blocks) if s * block < hi[i]}
+    assert live <= set(steps)
+
+
+def test_decode_blocks_fit_the_cells_caches():
+    """Blocks of whole lanes of positions that divide the cache, about
+    :data:`BLOCK_BYTES` of K a step: stablelm_3b's 32 heads of 80 take 256
+    positions, zamba2_7b's 32 heads of 224 take 128."""
+    from repro.kernels import decode_attention as da
+
+    assert da.block_sizes(32, 80, 2048) == (32, 256)
+    assert da.block_sizes(32, 224, 2048) == (32, 128)
+    assert da.block_sizes(4, 128, 2048) == (4, 1024)
+    assert da.block_sizes(4, 16, 32) == (4, 32)
+    assert da.block_sizes(4, 128, 640) == (4, 640)

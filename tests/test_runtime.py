@@ -277,3 +277,29 @@ def test_server_spans_each_decode_step_and_fill(telemetry):
     for e in fills[2:]:
         step = by_id[e.parent_id]
         assert step.ts <= e.ts and e.ts + e.dur <= step.ts + step.dur
+
+
+def test_server_counts_the_kv_blocks_each_decode_step_reads(telemetry, monkeypatch):
+    """Each ``serve.step`` holds the share of each cache entry's stored
+    blocks that the decode-attention kernel copies for the active slots'
+    positions, and the counters sum them: a request of prompt ``P`` and
+    ``n`` tokens takes ``n - 1`` decode steps at positions ``P .. P + n - 2``,
+    each reading its blocks up to its position (at least one)."""
+    from repro import obs
+    from repro.kernels import decode_attention
+
+    monkeypatch.setattr(decode_attention, "BLOCK_BYTES", 1)  # one lane of positions a block
+    cfg = reduced_config("stablelm_3b")
+    params, _ = Model(cfg).init(jax.random.PRNGKey(0))
+    scfg = ServeConfig(batch_slots=2, max_len=256, max_new_tokens=4, eos=-1)
+    prompts = [1, 125, 127, 200]
+    reqs = [Request(uid=i, prompt=np.ones((n,), np.int32)) for i, n in enumerate(prompts)]
+    Server(cfg, scfg, params).serve(reqs)
+
+    steps = [e for e in telemetry.events if e.name == "serve.step"]
+    assert steps and all(0 < e.attrs["kv_read_share"] <= 1 for e in steps)
+    new = scfg.max_new_tokens - 1
+    read = sum(max(1, -(-(p + t) // 128)) for p in prompts for t in range(new))
+    counters = obs.metrics().snapshot()
+    assert counters["serve.kv_blocks_read"] == read
+    assert counters["serve.kv_blocks_stored"] == 2 * new * len(prompts)
