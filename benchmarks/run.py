@@ -7,8 +7,6 @@ Sections:
   fig7          post-spilling optimization ablation    (paper Fig. 7)
   fig8          candidate-strategy comparison          (paper Fig. 8)
   fig9          predictor vs oracle vs naive           (paper Fig. 9)
-  roofline      dry-run three-term roofline per cell   (EXPERIMENTS §Roofline)
-  tpu_selector  TPU-adapted variant selector           (EXPERIMENTS §TPU)
   binary        pseudo-cubin codec throughput + sizes  (writes BENCH_binary.json)
   pipeline      batch-translate throughput, cache hit rate, per-pass breakdown
                 (writes BENCH_pipeline.json)
@@ -46,7 +44,7 @@ def main() -> None:
         default=None,
         metavar="SECTION[,SECTION...]",
         help="run only these sections (comma-separated, repeatable): "
-             "table1|fig6|fig7|fig8|fig9|roofline|tpu_selector|binary|"
+             "table1|fig6|fig7|fig8|fig9|binary|"
              "pipeline|sim|arch|search|corpus|obs|serve",
     )
     ap.add_argument("--binary-json", default=None, metavar="PATH",
@@ -88,11 +86,9 @@ def main() -> None:
         obs_bench,
         paper_figs,
         pipeline_bench,
-        roofline,
         search_bench,
         serve_bench,
         sim_bench,
-        tpu_selector,
     )
 
     def binary_rows():
@@ -131,8 +127,6 @@ def main() -> None:
         "fig7": paper_figs.fig7_postopt,
         "fig8": paper_figs.fig8_candidates,
         "fig9": paper_figs.fig9_predictor,
-        "roofline": roofline.roofline_rows,
-        "tpu_selector": tpu_selector.selector_rows,
         "binary": binary_rows,
         "pipeline": pipeline_rows,
         "sim": sim_rows,

@@ -1,21 +1,15 @@
 from .base import (
     ARCH_IDS,
-    ShapeCell,
-    active_param_count,
     all_configs,
     get_config,
     param_count,
     reduced_config,
-    shape_cells,
 )
 
 __all__ = [
     "ARCH_IDS",
-    "ShapeCell",
-    "active_param_count",
     "all_configs",
     "get_config",
     "param_count",
     "reduced_config",
-    "shape_cells",
 ]

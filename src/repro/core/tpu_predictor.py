@@ -1,4 +1,4 @@
-"""The RegDem performance predictor, adapted to XLA artifacts (DESIGN.md §2).
+"""The RegDem performance predictor, adapted to XLA artifacts.
 
 The paper's contract: *statically rank code variants from the compiled
 binary, never run the worst one, tie-break toward more optimizations*.
@@ -12,10 +12,10 @@ the three-term roofline:
 mirroring eq. 2/3's structure (per-unit contention + an empirical
 adjustment).  ``alpha`` plays the role of the f(occupancy) fit: it was
 calibrated so the ranking matches the measured ordering on the cells where
-several variants were lowered (see EXPERIMENTS.md §Perf).
+several variants were lowered.
 
-The selector consumes records produced by :mod:`repro.launch.dryrun`
-(flops / bytes / wire collective bytes per device) and returns the
+The selector consumes records of a compiled variant (flops, bytes and wire
+collective bytes per device, and its memory analysis) and returns the
 variant to ship, exactly like :func:`repro.core.predictor.predict` does
 for SASS variants.
 """
@@ -25,7 +25,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
-#: TPU v5e per-chip constants (same as benchmarks.roofline)
+#: TPU v5e per-chip constants
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 LINK_BW = 50e9
@@ -65,7 +65,7 @@ class VariantCost:
 
 def cost_from_record(rec: Dict[str, Any], name: Optional[str] = None,
                      hbm_bytes: int = 16 * 2**30, n_options: int = 0) -> VariantCost:
-    """Build a VariantCost from a dry-run record."""
+    """Build a VariantCost from a compiled variant's record."""
     wire = rec["collectives"].get("wire_bytes", rec["collectives"]["total_bytes"])
     mem = rec["memory"]
     used = mem["argument_bytes"] + mem["temp_bytes"] + mem["output_bytes"]

@@ -1,3 +1,3 @@
-from .pipeline import DataConfig, SyntheticLM, make_batch_shapes
+from .pipeline import DataConfig, SyntheticLM
 
-__all__ = ["DataConfig", "SyntheticLM", "make_batch_shapes"]
+__all__ = ["DataConfig", "SyntheticLM"]

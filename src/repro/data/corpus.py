@@ -14,9 +14,9 @@ Extraction model (deterministic, pure arithmetic — golden-pinned in
 
 * **block geometry** comes from the kernels' own tilers
   (:func:`~repro.kernels.flash_attention.choose_block_sizes`, the SSD
-  head-block formula), at the serving shapes of :data:`repro.configs.base.
-  SHAPES` (``prefill_32k`` / ``decode_32k``, clamped to per-model limits
-  such as whisper's 1500-frame encoder);
+  head-block formula), at fixed serving shapes (a 32k prefill of 32 rows
+  and a 32k decode of 128 rows, clamped to per-model limits such as
+  whisper's 1500-frame encoder);
 * **threads/block** is one thread per q-row (attention) or per head-block
   lane group (SSD), clamped to the launchable [64, 256] range;
 * **registers** count the per-thread live state the VMEM scratch holds on
@@ -46,7 +46,7 @@ from typing import Dict, List, Optional
 
 from repro.core.kernelgen import N_FIXED, Profile
 
-#: serving shape cells (mirrors repro.configs.base.SHAPES, serving subset)
+#: serving shapes: prefill and decode rows at a 32k context
 PREFILL_SEQ, PREFILL_BATCH = 32_768, 32
 DECODE_SEQ, DECODE_BATCH = 32_768, 128
 

@@ -19,7 +19,7 @@ loss decreases measurably — the end-to-end example trains on it.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Iterator, Tuple
+from typing import Dict, Iterator
 
 import numpy as np
 
@@ -93,23 +93,3 @@ class SyntheticLM:
             yield self.batch(i)
             i += 1
 
-
-def make_batch_shapes(
-    family: str,
-    global_batch: int,
-    seq_len: int,
-    d_model: int = 0,
-    n_patches: int = 0,
-    n_frames: int = 0,
-) -> Dict[str, Tuple[Tuple[int, ...], str]]:
-    """(shape, dtype) stand-ins per input for ``input_specs()`` (dry-run)."""
-    shapes: Dict[str, Tuple[Tuple[int, ...], str]] = {
-        "tokens": ((global_batch, seq_len), "int32"),
-        "targets": ((global_batch, seq_len), "int32"),
-    }
-    if family == "vlm":
-        shapes["patch_embeds"] = ((global_batch, n_patches, d_model), "bfloat16")
-        shapes["mrope_positions"] = ((global_batch, seq_len, 3), "int32")
-    if family == "audio":
-        shapes["frame_embeds"] = ((global_batch, n_frames, d_model), "bfloat16")
-    return shapes

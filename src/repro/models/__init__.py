@@ -7,8 +7,8 @@
 * ``hybrid``                     -> :mod:`repro.models.hybrid` (Zamba2)
 * ``audio``                      -> :mod:`repro.models.encdec` (Whisper)
 
-Every family exposes the same four entry points used by the trainer, the
-server and the dry-run:
+Every family exposes the same four entry points used by the trainer and
+the server:
 
     init(rng)                          -> (params, logical_axes)
     train_loss(params, batch)          -> scalar loss
@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 from . import encdec, hybrid, mamba2, scopes, transformer
-from .common import scan as common_scan
 from .transformer import BIG, ModelConfig, MoEConfig
 
 Pytree = Any
@@ -42,8 +41,9 @@ class Model:
     # -- init -----------------------------------------------------------------
 
     def abstract_init(self) -> Tuple[Pytree, Pytree]:
-        """(ShapeDtypeStruct params, logical axes) without allocating anything
-        — used by the dry-run to stand in for multi-billion-param weights."""
+        """(ShapeDtypeStruct params, logical axes) without allocating anything:
+        what stands in for multi-billion-parameter weights when compiling or
+        counting them."""
         box: Dict[str, Any] = {}
 
         def capture(k):
@@ -127,7 +127,7 @@ class Model:
         fn = body
         if self.remat in ("dots", "full"):
             fn = jax.checkpoint(body, prevent_cse=False)
-        h, (nssm, nconv) = common_scan(fn, h, (params["mamba"], ssm_states, conv_states))
+        h, (nssm, nconv) = jax.lax.scan(fn, h, (params["mamba"], ssm_states, conv_states))
         h = transformer.rms_norm(h, params["final_ln"])
         return h, {"ssm": nssm, "conv": nconv}
 
@@ -233,20 +233,20 @@ class Model:
             return h, {"kv": new_kv, "enc": state["enc"], "pos": state["pos"] + 1}
         raise ValueError(cfg.family)
 
-    def decode_kv_blocks(self, positions, max_len: int) -> Optional[Tuple[int, int]]:
+    def decode_kv_blocks(self, positions, state: Pytree) -> Optional[Tuple[int, int]]:
         """(blocks copied, blocks stored) of each KV cache entry (a layer's,
         or an application's of a shared block) when a decode step of slots
-        at ``positions`` reads ``max_len``-position caches through the
-        decode-attention kernel, counted on the host for full attention;
-        None for a family whose decode step does not read a cache that way."""
-        cfg = self.cfg
-        if cfg.family not in ("dense", "moe", "vlm", "hybrid"):
+        at ``positions`` reads the batch ``state``'s caches, ``(N, B, S, Hkv,
+        Dh)`` under ``kv``, through the decode-attention kernel, counted on
+        the host for full attention; None for a family whose decode step
+        does not read a cache that way."""
+        if self.cfg.family not in ("dense", "moe", "vlm", "hybrid"):
             return None
         from repro.kernels import decode_attention
 
-        cache_dtype = cfg.dtype if cfg.family == "hybrid" else jnp.bfloat16  # as prefill makes it
-        _, block = decode_attention.block_sizes(cfg.n_kv_heads, cfg.dh, max_len,
-                                                jnp.dtype(cache_dtype).itemsize)
+        cache = state["kv"][0]
+        _, _, max_len, n_kv_heads, dh = cache.shape
+        _, block = decode_attention.block_sizes(n_kv_heads, dh, max_len, cache.dtype.itemsize)
         return decode_attention.read_share(positions, max_len, block)
 
     def logits(self, params: Pytree, h: jax.Array) -> jax.Array:

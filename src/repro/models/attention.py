@@ -6,7 +6,7 @@
                *register-demotion adapted* formulation: the running
                (m, l, acc) statistics stay in the scan carry (registers /
                VMEM once compiled) instead of materializing scores to HBM.
-               Memory O(Sq x chunk), required for the 32k/500k shape cells.
+               Memory O(Sq x chunk), so long contexts fit.
 * ``pallas``   the TPU kernel (:mod:`repro.kernels.flash_attention`), same
                math with explicit VMEM scratch residency.
 
@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from . import scopes
-from .common import scan as common_scan, NEG_INF, causal_mask_bias
+from .common import NEG_INF, causal_mask_bias
 
 DEFAULT_CHUNK = 1024
 
@@ -125,7 +125,7 @@ def attention_chunked(
     m0 = jnp.full((b, hq, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hq, sq), jnp.float32)
     a0 = jnp.zeros((b, sq, hq, dh), jnp.float32)
-    (m, l, acc), _ = common_scan(step, (m0, l0, a0), (kc, vc, pc))
+    (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0), (kc, vc, pc))
     l = jnp.maximum(l, 1e-30)
     out = acc / l.transpose(0, 2, 1)[..., None]
     return out.astype(q.dtype)

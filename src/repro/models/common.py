@@ -3,12 +3,11 @@
 Everything is a plain function over pytrees of ``jnp`` arrays — no Flax/NNX
 dependency — so that parameter sharding stays a pure metadata concern
 (:mod:`repro.sharding`) and layer stacks can be ``jax.lax.scan``-ed with
-O(1) HLO size in depth (required for the 512-device dry-run).
+O(1) HLO size in depth.
 """
 
 from __future__ import annotations
 
-import contextlib
 import math
 from typing import Any, Optional, Tuple
 
@@ -16,34 +15,6 @@ import jax
 import jax.numpy as jnp
 
 Pytree = Any
-
-# ---------------------------------------------------------------------------
-# Scan unrolling (dry-run accounting mode)
-# ---------------------------------------------------------------------------
-
-#: When True, every lax.scan in the model stack is fully unrolled.  The
-#: dry-run uses this so ``compiled.cost_analysis()`` counts loop bodies the
-#: correct number of times (XLA's analysis counts a while body once) and the
-#: static HLO collective parse is exact.  Real runs keep scans rolled.
-_UNROLL = {"on": False}
-
-
-@contextlib.contextmanager
-def unrolled_scans():
-    prev = _UNROLL["on"]
-    _UNROLL["on"] = True
-    try:
-        yield
-    finally:
-        _UNROLL["on"] = prev
-
-
-def scan(f, init, xs, **kw):
-    """lax.scan honouring the dry-run unroll switch."""
-    if _UNROLL["on"]:
-        kw = dict(kw, unroll=True)
-    return jax.lax.scan(f, init, xs, **kw)
-
 
 # ---------------------------------------------------------------------------
 # Initialization

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 
 from . import scopes
 from .attention import attention, attention_decode_at
-from .common import scan as common_scan, apply_rope, dense_init, rms_norm, trunc_normal
+from .common import apply_rope, dense_init, rms_norm, trunc_normal
 from .mamba2 import D_CONV, init_mamba_layer, mamba_dims, mamba_layer
 from .transformer import ModelConfig, lm_loss, write_token_rows
 
@@ -213,7 +213,7 @@ def _mamba_layers(cfg: ModelConfig, params, h, t, lo: int, hi: int, states, deco
     if remat in ("dots", "full"):
         body = jax.checkpoint(body, prevent_cse=False)
     t = jnp.zeros_like(h) if t is None else t.astype(h.dtype)
-    (h, _, states), _ = common_scan(body, (h, t, states), jnp.arange(hi - lo))
+    (h, _, states), _ = jax.lax.scan(body, (h, t, states), jnp.arange(hi - lo))
     return h, states
 
 

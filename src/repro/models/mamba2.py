@@ -4,7 +4,7 @@ Chunked SSD algorithm in pure JAX: within chunks of length ``Q`` the
 recurrence is computed in its quadratic "attention-like" dual form; across
 chunks a ``jax.lax.scan`` carries the (H, P, N) recurrent state.
 
-Register-demotion connection (DESIGN.md §2): the carried chunk state is the
+Register-demotion connection: the carried chunk state is the
 demoted-register analogue — it stays resident (registers/VMEM) across the
 chunk loop instead of being re-materialized from HBM, and the Pallas kernel
 (:mod:`repro.kernels.mamba2_ssd`) makes that residency explicit with VMEM
@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from . import scopes
-from .common import scan as common_scan, rms_norm, trunc_normal
+from .common import rms_norm, trunc_normal
 
 Pytree = Any
 
@@ -147,7 +147,7 @@ def ssd_chunked(
         return h_new, h  # emit state *before* this chunk
 
     init = h0 if h0 is not None else jnp.zeros((B, H, P, N), jnp.float32)
-    h_last, h_prevs = common_scan(
+    h_last, h_prevs = jax.lax.scan(
         scan_step,
         init.astype(jnp.float32),
         (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)),

@@ -9,7 +9,7 @@ granite-8b, qwen2-moe-a2.7b, llama4-scout and the qwen2-vl-2b backbone:
 * dense SwiGLU or MoE FFN (shared + routed experts, top-k, capacity-based
   scatter dispatch so compiled FLOPs reflect *active* experts only);
 * layer stacks are scanned (``jax.lax.scan``) over stacked parameters:
-  HLO size is O(1) in depth, which keeps the 512-device dry-run tractable;
+  HLO size is O(1) in depth;
 * three step flavours: ``train`` (full seq), ``prefill`` (returns KV cache),
   ``decode`` (one token against the cache, which it reads in place and
   writes only at the token's rows).
@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from . import scopes
 from .attention import attention, attention_decode_at
-from .common import scan as common_scan, apply_mrope, apply_rope, rms_norm, swiglu, trunc_normal
+from .common import apply_mrope, apply_rope, rms_norm, swiglu, trunc_normal
 
 Pytree = Any
 
@@ -85,7 +85,7 @@ class ModelConfig:
     num_mem_blocks: int = 1
     adapter_rank: int = 0
     dtype: Any = jnp.bfloat16
-    # notes for DESIGN.md §Arch-applicability
+    # free-text notes on the architecture
     notes: str = ""
 
     @property
@@ -466,10 +466,10 @@ def forward(
 
     if kv_caches is not None:
         xs = (params["layers"], kinds, jnp.arange(cfg.n_layers))
-        (h, ck, cv), _ = common_scan(body, (h,) + tuple(kv_caches), xs)
+        (h, ck, cv), _ = jax.lax.scan(body, (h,) + tuple(kv_caches), xs)
         new_caches = (ck, cv)
     else:
-        h, new_caches = common_scan(body, h, (params["layers"], kinds))
+        h, new_caches = jax.lax.scan(body, h, (params["layers"], kinds))
 
     h = rms_norm(h, params["final_ln"])
     return h, new_caches
@@ -502,7 +502,7 @@ def decode(
         return decode_block(cfg, h, lp, kind, positions[:, None], kv_caches, i)
 
     xs = (params["layers"], cfg.layer_kinds(), jnp.arange(cfg.n_layers))
-    h, rows = common_scan(scan_body, h, xs)  # (L, B, 1, Hkv, Dh) x2
+    h, rows = jax.lax.scan(scan_body, h, xs)  # (L, B, 1, Hkv, Dh) x2
     new_caches = write_token_rows(kv_caches, rows, positions)
     h = rms_norm(h, params["final_ln"])
     return h, new_caches
@@ -570,7 +570,7 @@ def lm_loss(
     # chunk's (B, c, V) logits — reassembling exactly the full-logits tensor
     # the chunking exists to avoid
     step = jax.checkpoint(step, prevent_cse=False)
-    (total, count), _ = common_scan(step, (jnp.float32(0.0), jnp.int32(0)), (hc, tc))
+    (total, count), _ = jax.lax.scan(step, (jnp.float32(0.0), jnp.int32(0)), (hc, tc))
     return total / jnp.maximum(count, 1)
 
 

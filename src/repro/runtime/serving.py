@@ -7,8 +7,8 @@ A small but real serving loop:
   the request queue with a fresh prefill — prefill writes into the shared
   KV cache at that slot;
 * greedy or temperature sampling;
-* the decode step is a single jitted function over the cache pytree — this
-  is the ``serve_step`` the decode/long-context dry-run cells lower;
+* the decode step is a single jitted function over the cache pytree, which
+  ``tests/test_chip_compile.py`` compiles for a described v5e;
 * with greedy sampling and no EOS the next decode step is queued on the
   device before the host waits for this one's tokens, so the host's
   bookkeeping overlaps the device's work.
@@ -55,8 +55,8 @@ class Completion:
 
 
 class Server:
-    """Single-host reference server; the same step functions lower on the
-    production mesh (see launch/dryrun.py serve cells)."""
+    """Single-host reference server; ``tests/test_chip_compile.py`` compiles
+    its step functions for a described v5e."""
 
     def __init__(self, model_cfg: ModelConfig, cfg: ServeConfig, params: Pytree):
         self.model = Model(model_cfg, attn_impl="chunked")
@@ -187,7 +187,7 @@ class Server:
             active = sum(r is not None for r in slot_req)
             with obs.span("serve.step", step=step, active=active) as step_span:
                 if obs.enabled():
-                    self._count_kv_reads(step_span, [
+                    self._count_kv_reads(step_span, state, [
                         len(req.prompt) + len(slot_tokens[slot]) - 1
                         for slot, req in enumerate(slot_req) if req is not None])
                 if queued is None:
@@ -225,10 +225,10 @@ class Server:
             step += 1
         return sorted(done, key=lambda c: c.uid)
 
-    def _count_kv_reads(self, step_span, positions: List[int]) -> None:
+    def _count_kv_reads(self, step_span, state: Pytree, positions: List[int]) -> None:
         """``kv_read_share`` on ``step_span`` and its counters, for a decode
-        step of slots at ``positions``."""
-        blocks = self.model.decode_kv_blocks(positions, self.cfg.max_len)
+        step of slots at ``positions`` over the batch ``state``."""
+        blocks = self.model.decode_kv_blocks(positions, state)
         if blocks is None:
             return
         read, stored = blocks

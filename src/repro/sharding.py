@@ -1,21 +1,17 @@
 """Logical-axis sharding rules (GSPMD / MaxText style).
 
 Every parameter and activation carries a tuple of *logical* axis names; a
-per-run rule table maps logical names to mesh axes.  The production meshes
-(:mod:`repro.launch.mesh`) expose axes ``("data", "model")`` single-pod and
-``("pod", "data", "model")`` multi-pod; the pod axis extends data
-parallelism across pods (gradient all-reduce crosses the DCI/ICI boundary
-once per step).
+per-run rule table maps logical names to mesh axes, those of the
+``("data", "model")`` meshes of :mod:`repro.launch.mesh`.
 
 Default rule set:
 
 * ``embed``/``ff``/``heads``/``vocab``   -> tensor parallel over ``model``
 * ``layers``/norm scales                 -> replicated
-* ``batch``                             -> data parallel over ``(pod, data)``
+* ``batch``                             -> data parallel over ``data``
 * ``expert``                            -> expert parallel over ``model`` when
   the expert count divides the model axis; otherwise experts replicate and
-  ``ff_expert`` takes the model axis (TP inside experts) — see
-  DESIGN.md §Arch-applicability.
+  ``ff_expert`` takes the model axis (TP inside experts).
 * optional FSDP: parameters additionally shard their ``embed``/``ff`` (dim0)
   axis over ``data`` (zero-3 style), controlled per run.
 """
@@ -23,7 +19,7 @@ Default rule set:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import numpy as np
@@ -61,8 +57,7 @@ def default_rules(
 ) -> ShardingRules:
     axes = mesh.axis_names
     model_ax = "model" if "model" in axes else None
-    data_axes = tuple(a for a in ("pod", "data") if a in axes)
-    dp: Any = data_axes if len(data_axes) > 1 else (data_axes[0] if data_axes else None)
+    dp = "data" if "data" in axes else None
     model_size = mesh.shape.get("model", 1) if model_ax else 1
 
     expert_ax: Any = None
@@ -129,28 +124,3 @@ def logical_to_sharding(
         return jax.tree.map(to_sharding, axes_tree, is_leaf=is_axes_leaf)
     return jax.tree.map(to_sharding, axes_tree, like, is_leaf=is_axes_leaf)
 
-
-def batch_specs(mesh: Mesh, batch_shapes: Dict[str, Tuple[int, ...]], rules: ShardingRules) -> Dict[str, NamedSharding]:
-    """Shardings for a model input batch: dim0 = batch (data parallel)."""
-    out = {}
-    for name, shape in batch_shapes.items():
-        spec = [rules.get("batch")] + [None] * (len(shape) - 1)
-        out[name] = NamedSharding(mesh, P(*spec))
-    return out
-
-
-def check_divisibility(cfg, mesh: Mesh, global_batch: int) -> list[str]:
-    """Static validation that a (config x mesh x batch) cell is shardable.
-
-    Returns a list of human-readable problems (empty = OK).  Called by the
-    dry-run before lowering so failures are diagnosed, not debugged from
-    XLA errors.
-    """
-    problems = []
-    model = mesh.shape.get("model", 1)
-    data = int(np.prod([mesh.shape.get(a, 1) for a in ("pod", "data")]))
-    if global_batch % data and global_batch >= data:
-        problems.append(f"global_batch {global_batch} % data {data} != 0")
-    if cfg.n_heads % model and cfg.n_heads >= model:
-        problems.append(f"n_heads {cfg.n_heads} % model {model} != 0")
-    return problems

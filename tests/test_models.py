@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import ARCH_IDS, get_config, param_count, reduced_config, shape_cells
+from repro.configs import ARCH_IDS, get_config, param_count, reduced_config
 from repro.models import Model, hybrid, transformer
 from repro.models.attention import attention_chunked, attention_xla
 from repro.runtime.serving import Server
@@ -172,21 +172,26 @@ def test_full_configs_match_assignment():
     assert get_config("zamba2_7b").ssm_groups == 2
 
 
-def test_shape_cells_cover_assignment():
-    total = skipped = 0
-    for arch in ARCH_IDS:
-        cells = shape_cells(arch)
-        assert [c.name for c in cells] == ["train_4k", "prefill_32k", "decode_32k", "long_500k"]
-        total += len(cells)
-        skipped += sum(c.skipped for c in cells)
-        # long_500k runs exactly for the sub-quadratic archs
-        long = cells[-1]
-        if arch in ("gemma3_1b", "llama4_scout_17b_a16e", "mamba2_370m", "zamba2_2_7b"):
-            assert not long.skipped, arch
-        else:
-            assert long.skipped, arch
-    assert total == 40
-    assert skipped == 6
+#: each configuration's parameter count, the elements of ``Model.abstract_init``
+PARAM_COUNTS = {
+    "stablelm_3b": 2_795_276_800,
+    "gemma3_1b": 999_812_736,
+    "qwen2_7b": 7_615_616_512,
+    "granite_8b": 8_254_689_280,
+    "qwen2_moe_a2_7b": 14_315_784_192,
+    "llama4_scout_17b_a16e": 107_769_861_120,
+    "qwen2_vl_2b": 1_546_073_600,
+    "whisper_large_v3": 1_955_671_040,
+    "mamba2_370m": 368_338_432,
+    "zamba2_2_7b": 2_652_711_840,
+    "zamba2_7b": 7_356_749_648,
+}
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS + ["zamba2_7b"])
+def test_param_count_is_pinned(arch):
+    """A change to a family's parameter layout changes its count here."""
+    assert param_count(get_config(arch)) == PARAM_COUNTS[arch]
 
 
 def test_param_counts_plausible():
