@@ -233,21 +233,23 @@ class Model:
             return h, {"kv": new_kv, "enc": state["enc"], "pos": state["pos"] + 1}
         raise ValueError(cfg.family)
 
-    def decode_kv_blocks(self, positions, state: Pytree) -> Optional[Tuple[int, int]]:
-        """(blocks copied, blocks stored) of each KV cache entry (a layer's,
-        or an application's of a shared block) when a decode step of slots
-        at ``positions`` reads the batch ``state``'s caches, ``(N, B, S, Hkv,
-        Dh)`` under ``kv``, through the decode-attention kernel, counted on
-        the host for full attention; None for a family whose decode step
-        does not read a cache that way."""
+    def decode_kv_traffic(self, positions, state: Pytree) -> Optional[Tuple[int, int, int]]:
+        """(blocks copied, blocks stored, bytes rewritten) when a decode step
+        of slots at ``positions`` goes through the batch ``state``'s caches,
+        ``(N, B, S, Hkv, Dh)`` under ``kv``, counted on the host: the blocks of
+        each cache entry (a layer's, or an application's of a shared block)
+        that the decode-attention kernel reads for full attention, and the
+        bytes of the tiles that the row-write kernel rewrites, K and V
+        together; None for a family whose decode step does not use them."""
         if self.cfg.family not in ("dense", "moe", "vlm", "hybrid"):
             return None
-        from repro.kernels import decode_attention
+        from repro.kernels import decode_attention, kv_write
 
         cache = state["kv"][0]
         _, _, max_len, n_kv_heads, dh = cache.shape
         _, block = decode_attention.block_sizes(n_kv_heads, dh, max_len, cache.dtype.itemsize)
-        return decode_attention.read_share(positions, max_len, block)
+        written = sum(kv_write.write_bytes(c.shape, c.dtype.itemsize) for c in state["kv"])
+        return (*decode_attention.read_share(positions, max_len, block), written)
 
     def logits(self, params: Pytree, h: jax.Array) -> jax.Array:
         cfg = self.cfg

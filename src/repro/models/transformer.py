@@ -488,9 +488,9 @@ def decode(
     The caches are not carried through the layer scan, where the compiler
     copies each layer's slice out and back: each layer reads its slice where
     it is stored (:func:`attention_decode_at`), and the layers' new rows come
-    out of the scan and are written after it, slot by slot, in place when
-    the caches are donated.  One scatter of all the rows would make the
-    compiler copy both caches into another layout around it.
+    out of the scan and are written after it (:func:`write_token_rows`), in
+    place when the caches are donated.  One scatter of all the rows would
+    make the compiler copy both caches into another layout around it.
     """
     B, S = tokens.shape
     if S != 1:
@@ -514,9 +514,11 @@ def write_token_rows(
     positions: jax.Array,  # (B,)
 ) -> Tuple[jax.Array, jax.Array]:
     """Each slot's new k and v rows written at its position in every layer,
-    one ``dynamic_update_slice`` per slot and cache: in place when the
-    caches are donated."""
-    with jax.named_scope(scopes.KV_WRITE):
+    in place when the caches are donated: on a TPU by the Pallas kernel
+    (:mod:`repro.kernels.kv_write`), which rewrites the aligned window of
+    positions that holds each row, in the caches' stored layout; elsewhere
+    one ``dynamic_update_slice`` per slot and cache."""
+    def reference():
         def write(b, caches):
             at = (0, b, positions[b], 0, 0)
             return tuple(
@@ -527,6 +529,17 @@ def write_token_rows(
         # unrolled: for caches with a head size of 128 the TPU compiler
         # fails (an internal RET_CHECK) on a rolled loop here
         return jax.lax.fori_loop(0, positions.shape[0], write, tuple(caches), unroll=True)
+
+    def kernel():
+        from repro.kernels.kv_write import write_rows
+
+        # positions are the minor dimension of the stored caches: this view is those bytes
+        k, v = write_rows(*(jnp.moveaxis(c, 2, -1) for c in caches),
+                          *(r[:, :, 0] for r in rows), positions)
+        return jnp.moveaxis(k, -1, 2), jnp.moveaxis(v, -1, 2)
+
+    with jax.named_scope(scopes.KV_WRITE):
+        return jax.lax.platform_dependent(tpu=kernel, default=reference)
 
 
 def lm_head(cfg: ModelConfig, params: Pytree, h: jax.Array) -> jax.Array:

@@ -122,7 +122,9 @@ class Server:
         kernel, a ``serve.step`` also holds ``kv_read_share``: the share of
         each cache entry's stored blocks that the kernel copies for the
         active slots' positions, whose sums are the counters
-        ``serve.kv_blocks_read`` and ``serve.kv_blocks_stored``.
+        ``serve.kv_blocks_read`` and ``serve.kv_blocks_stored``; and
+        ``kv_write_bytes``: the bytes of the tiles the row-write kernel
+        rewrites, which the counter ``serve.kv_write_bytes`` sums.
 
         Greedy and without EOS, a decode step's input is the last step's
         tokens, which stay on the device, and a request ends at its count
@@ -187,7 +189,7 @@ class Server:
             active = sum(r is not None for r in slot_req)
             with obs.span("serve.step", step=step, active=active) as step_span:
                 if obs.enabled():
-                    self._count_kv_reads(step_span, state, [
+                    self._count_kv_traffic(step_span, state, [
                         len(req.prompt) + len(slot_tokens[slot]) - 1
                         for slot, req in enumerate(slot_req) if req is not None])
                 if queued is None:
@@ -225,16 +227,18 @@ class Server:
             step += 1
         return sorted(done, key=lambda c: c.uid)
 
-    def _count_kv_reads(self, step_span, state: Pytree, positions: List[int]) -> None:
-        """``kv_read_share`` on ``step_span`` and its counters, for a decode
-        step of slots at ``positions`` over the batch ``state``."""
-        blocks = self.model.decode_kv_blocks(positions, state)
-        if blocks is None:
+    def _count_kv_traffic(self, step_span, state: Pytree, positions: List[int]) -> None:
+        """``kv_read_share`` and ``kv_write_bytes`` on ``step_span`` and their
+        counters, for a decode step of slots at ``positions`` over the batch
+        ``state``."""
+        traffic = self.model.decode_kv_traffic(positions, state)
+        if traffic is None:
             return
-        read, stored = blocks
-        step_span.set(kv_read_share=read / stored)
+        read, stored, written = traffic
+        step_span.set(kv_read_share=read / stored, kv_write_bytes=written)
         obs.metrics().counter("serve.kv_blocks_read").inc(read)
         obs.metrics().counter("serve.kv_blocks_stored").inc(stored)
+        obs.metrics().counter("serve.kv_write_bytes").inc(written)
 
     # -- slot surgery -------------------------------------------------------------
     # State leaves keyed by their top-level name:

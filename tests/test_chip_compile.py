@@ -211,15 +211,69 @@ def _assert_kernel_reads_the_stacked_caches(text: str, stack_shape, kernels: int
         assert len(set(caches)) == 2, caches
 
 
+def _tuple_element(instructions, name: str):
+    """(tuple, index) of the get-tuple-element that instruction ``name`` is,
+    through bitcasts; None where it is another value."""
+    while instructions[name][1] == "bitcast":
+        name = instructions[name][2][0]
+    _, op, operands, line = instructions[name]
+    if op != "get-tuple-element":
+        return None
+    return operands[0], int(re.search(r"\bindex=(\d+)", line).group(1))
+
+
+def _assert_write_kernel_rewrites_the_stacked_caches(text: str, stack_shape):
+    """One row-write custom call, in the ``kv_write`` scope, whose cache
+    operands are the step's stacked cache parameters themselves and whose
+    outputs, aliased to them, are the step's new caches, which the step
+    aliases to those parameters: the caches are rewritten where they are
+    stored.  No dynamic-update-slice, fused or not, yields a stack of caches
+    or a cache, as the model holds them or as the kernels see them."""
+    from chipbench.scopes import instruction_scopes
+    from repro.models import scopes
+
+    instructions, entry = _instructions(text)
+    calls = {name: operands for name, (_, op, operands, line) in instructions.items()
+             if op == "custom-call" and "tpu_custom_call" in line and name.startswith("kv_write")}
+    assert len(calls) == 1, sorted(calls)
+    (name, operands), = calls.items()
+    assert instruction_scopes(text, scopes.ALL)[name] == scopes.KV_WRITE, name
+    shape = "bf16[%s]" % ",".join(map(str, stack_shape))
+    caches = [_entry_parameter(instructions, entry, o) for o in operands[-2:]]
+    assert all(c is not None and shape in instructions[c][3] for c in caches), caches
+    assert len(set(caches)) == 2, caches
+    n = len(operands)
+    assert f"output_to_operand_aliasing={{{{0}}: ({n - 2}, {{}}), {{1}}: ({n - 1}, {{}})}}" \
+        in instructions[name][3], instructions[name][3][:600]
+
+    header = text.splitlines()[0].split("entry_computation_layout")[0]
+    aliased = {int(o): int(p) for o, p in re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)}
+    root, = [i for i, (computation, _, _, line) in instructions.items()
+             if computation == entry and line.lstrip().startswith("ROOT")]
+    outputs = [_tuple_element(instructions, o) for o in instructions[root][2]]
+    for index, cache in enumerate(caches):
+        number = int(re.search(r"parameter\((\d+)\)", instructions[cache][3]).group(1))
+        out = [i for i, o in enumerate(outputs) if o == (name, index)]
+        assert len(out) == 1 and aliased.get(out[0]) == number, (cache, out, aliased)
+
+    slots, max_len, hkv, dh = stack_shape[1:]
+    cache_shapes = _cache_shapes(stack_shape[0], slots, max_len, hkv, dh)
+    updates = [line.strip()[:160] for line in text.splitlines()
+               if (m := _INSTRUCTION.match(line)) and "dynamic-update-slice" in m.group(1) + m.group(3)
+               and tuple(int(d) for d in m.group(2).split(",") if d) in cache_shapes]
+    assert not updates, updates
+
+
 def test_serving_decode_step_moves_no_cache_sized_data(one_chip):
     """``Server``'s decode step at the stablelm_3b serving cell's sizes (12
     slots x 2048, full widths) reads each layer's cache where it is stored,
-    through the decode-attention kernel, and writes only the token's rows:
-    the kernel takes the stacked cache parameters themselves, in the
-    ``attention`` scope, and no copy, select, scatter or transpose yields a
-    layer's slice or the stacked cache, as the model holds it or as the
-    kernel reads it.  The step needs under 64 MiB of temporaries beside its
-    arguments."""
+    through the decode-attention kernel, and writes only the windows that
+    hold the token's rows, through the row-write kernel: each kernel takes
+    the stacked cache parameters themselves, in its own scope, the write's
+    outputs are the step's new caches, aliased to them, and no copy, select,
+    scatter, transpose or dynamic-update-slice yields a layer's slice or the
+    stacked cache, as the model holds it or as the kernels see it.  The step
+    needs under 64 MiB of temporaries beside its arguments."""
     cell = Cell.load("stablelm_3b.decode_heavy")
     cfg = program_config(cell.config)
     slots, max_len = int(cell.config["serve"]["slots"]), int(cell.config["serve"]["max_len"])
@@ -228,8 +282,9 @@ def test_serving_decode_step_moves_no_cache_sized_data(one_chip):
 
     moved = _moved(text, _cache_shapes(cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.dh))
     assert not moved, moved
-    _assert_kernel_reads_the_stacked_caches(
-        text, (cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.dh), kernels=1)
+    stack = (cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.dh)
+    _assert_kernel_reads_the_stacked_caches(text, stack, kernels=1)
+    _assert_write_kernel_rewrites_the_stacked_caches(text, stack)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
@@ -247,11 +302,14 @@ def test_zamba2_cell_compiles_and_fits(one_chip):
     state and its temporaries in 15.75 GiB, reads each
     application's cache where it is stored, through the decode-attention
     kernel (one custom call an application, in the ``attention`` scope,
-    taking the stacked cache parameters themselves), and carries each
+    taking the stacked cache parameters themselves), rewrites the windows
+    that hold the new rows of every application in one call of the
+    row-write kernel (in the ``kv_write`` scope, on those parameters,
+    aliased to the step's new caches), and carries each
     segment's SSM state in the layout it is stored in: no copy, select,
     scatter or transpose yields an application's cache or the stacked
-    caches, as the model holds them or as the kernel reads them, or a
-    segment's SSM state.  A
+    caches, as the model holds them or as the kernels see them, or a
+    segment's SSM state, and no dynamic-update-slice a cache.  A
     1920-token prefill fits beside the weights and the batch state the
     server holds while it runs."""
     from chipbench.kinds.serve_zamba2 import program_config as zamba2_config
@@ -276,8 +334,9 @@ def test_zamba2_cell_compiles_and_fits(one_chip):
     text = compiled.as_text()
     moved = _moved(text, moved_shapes)
     assert not moved, moved
-    _assert_kernel_reads_the_stacked_caches(
-        text, (apps, slots, max_len, cfg.n_kv_heads, cfg.dh), kernels=apps)
+    stack = (apps, slots, max_len, cfg.n_kv_heads, cfg.dh)
+    _assert_kernel_reads_the_stacked_caches(text, stack, kernels=apps)
+    _assert_write_kernel_rewrites_the_stacked_caches(text, stack)
     # 399.5 MiB before the kernel, which adds its outputs and the queries' view
     assert mem.temp_size_in_bytes < 401 * 2**20, mem.temp_size_in_bytes / 2**20
 

@@ -272,3 +272,66 @@ def test_decode_blocks_fit_the_cells_caches():
     assert da.block_sizes(4, 128, 2048) == (4, 1024)
     assert da.block_sizes(4, 16, 32) == (4, 32)
     assert da.block_sizes(4, 128, 640) == (4, 640)
+
+
+# -- the decode step's row write into a stacked cache --------------------------
+
+KV_WRITE_SHAPES = [
+    # (Hkv, Dh, max_len)
+    pytest.param(32, 80, 256, id="mha_32x80"),
+    pytest.param(32, 224, 256, id="mha_32x224_heads_split"),
+    pytest.param(4, 128, 256, id="gqa_4x128"),
+    pytest.param(4, 128, 200, id="gqa_4x128_whole_cache"),
+]
+
+
+@pytest.mark.parametrize("entries", [1, 3])
+@pytest.mark.parametrize("hkv,dh,max_len", KV_WRITE_SHAPES)
+def test_kv_write_matches_dynamic_update_slice(hkv, dh, max_len, entries):
+    """The row-write kernel (interpret mode), through the caches' stored
+    view, leaves caches equal bit for bit to the unrolled
+    ``dynamic_update_slice`` loop's, every untouched lane included: slots at
+    position 0, either side of a 128-position window's edge, on it, at the
+    last position and past the end (clamped to it), in one batch."""
+    from repro.kernels.kv_write import write_rows
+    from repro.models.transformer import write_token_rows
+
+    positions = jnp.array([129, 0, max_len + 7, 127, max_len - 1, 128], jnp.int32)
+    b = positions.shape[0]
+    ks = jax.random.split(jax.random.PRNGKey(hkv + dh + entries), 4)
+    caches = tuple(jax.random.normal(k, (entries, b, max_len, hkv, dh), jnp.float32)
+                   .astype(jnp.bfloat16) for k in ks[:2])
+    rows = tuple(jax.random.normal(k, (entries, b, 1, hkv, dh), jnp.float32)
+                 .astype(jnp.bfloat16) for k in ks[2:])
+
+    want = write_token_rows(caches, rows, positions)
+    got = write_rows(*(jnp.moveaxis(c, 2, -1) for c in caches), *(r[:, :, 0] for r in rows),
+                     positions, interpret=True)
+    for g, w, c in zip(got, want, caches):
+        bits = lambda x: np.asarray(jax.lax.bitcast_convert_type(x, jnp.uint16))
+        np.testing.assert_array_equal(bits(jnp.moveaxis(g, -1, 2)), bits(w))
+        assert not np.array_equal(bits(w), bits(c))  # the rows did land
+
+
+def test_kv_write_blocks_fit_the_cells_caches():
+    """A window of 128 positions (the whole cache where it is not a multiple
+    of 128), and the most heads within :data:`BLOCK_BYTES` of K a step:
+    all 32 of stablelm_3b's heads of 80, 16 of zamba2_7b's 32 heads of 224."""
+    from repro.kernels import kv_write as kw
+
+    assert kw.block_sizes(32, 80, 2048) == (32, 128)
+    assert kw.block_sizes(32, 224, 2048) == (16, 128)
+    assert kw.block_sizes(4, 128, 2048) == (4, 128)
+    assert kw.block_sizes(4, 16, 32) == (4, 32)
+    assert kw.block_sizes(4, 128, 200) == (4, 200)
+
+
+def test_kv_write_bytes_count_whole_tiles():
+    """Every slot's window in every entry and head, ``Dh`` padded to the
+    bfloat16 tile's 16 rows: stablelm_3b's step rewrites 252 MB of each cache."""
+    from repro.kernels.kv_write import write_bytes
+
+    assert write_bytes((32, 12, 2048, 32, 80), 2) == 32 * 12 * 32 * 80 * 128 * 2
+    assert write_bytes((4, 24, 2048, 32, 224), 2) == 4 * 24 * 32 * 224 * 128 * 2
+    assert write_bytes((2, 3, 200, 4, 72), 2) == 2 * 3 * 4 * 80 * 200 * 2
+    assert write_bytes((2, 3, 256, 4, 72), 4) == 2 * 3 * 4 * 72 * 128 * 4

@@ -72,13 +72,12 @@ def test_mamba2_block_holds_the_ssd_scope(decode):
     assert {scopes.SSD, scopes.MAMBA} <= _scopes_in(text)
 
 
-@pytest.mark.parametrize("arch", ["stablelm_3b", "zamba2_7b"])
-def test_decode_kernel_falls_under_the_attention_scope(arch):
-    """Lowered for a TPU (no chip needed to lower), the decode step reads its
-    caches through the decode-attention kernel, called once per layer (in
-    the layer scan's body) or application, each call inside the
-    ``attention`` scope: the benchmark's split puts its device time there."""
-    cfg = reduced_config(arch)
+def _kernel_calls(cfg):
+    """Lowered for a TPU (no chip needed to lower), ``Server``'s decode step
+    for ``cfg``: {jitted kernel wrapper: (its kernel custom calls, the
+    location of each call of it)}.  A wrapper's custom call sits in a
+    function of its own, and the decode step calls that function where the
+    scopes are."""
     params = Model(cfg).abstract_init()[0]
     server = Server(cfg, ServeConfig(batch_slots=SLOTS, max_len=256), params)
     one = jax.eval_shape(server._prefill_fn, params, jax.ShapeDtypeStruct((1, 1), jnp.int32))[1]
@@ -87,10 +86,7 @@ def test_decode_kernel_falls_under_the_attention_scope(arch):
         one)
     lowered = server._decode.trace(params, jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32),
                                    state).lower(lowering_platforms=("tpu",))
-
-    # the kernel's wrapper is jitted: its custom call sits in a function of
-    # its own, and the decode step calls that function where the scopes are
-    calls, kernel_funcs = [], set()
+    calls, kernels = [], {}
 
     def walk(op, func):
         for region in op.regions:
@@ -105,15 +101,43 @@ def test_decode_kernel_falls_under_the_attention_scope(arch):
                                       str(inner.location)))
                     if (name == "stablehlo.custom_call"
                             and "tpu_custom_call" in str(inner.attributes["call_target_name"])):
-                        kernel_funcs.add(func)
+                        kernels[func] = kernels.get(func, 0) + 1
                     walk(inner.operation, func)
 
     walk(lowered.compiler_ir("stablehlo").operation, None)
-    assert len(kernel_funcs) == 1 and None not in kernel_funcs, kernel_funcs
-    sites = [loc for callee, loc in calls if callee in kernel_funcs]
+    assert None not in kernels, kernels
+    return {func: (n, [loc for callee, loc in calls if callee == func])
+            for func, n in kernels.items()}
+
+
+def _scopes_on(loc: str):
+    """The model's scopes on the name path of a call's location."""
+    return [part for part in loc.split('"')[1].split("/") if part in scopes.ALL]
+
+
+@pytest.mark.parametrize("arch", ["stablelm_3b", "zamba2_7b"])
+def test_decode_kernel_falls_under_the_attention_scope(arch):
+    """Lowered for a TPU, the decode step reads its caches through the
+    decode-attention kernel, called once per layer (in the layer scan's
+    body) or application, each call inside the ``attention`` scope: the
+    benchmark's split puts its device time there."""
+    cfg = reduced_config(arch)
+    kernels = _kernel_calls(cfg)
+    assert set(kernels) == {"decode_attention", "write_rows"}, kernels
+    n, sites = kernels["decode_attention"]
     apps = len(cfg.hybrid_layer_ids) if cfg.family == "hybrid" else 1  # a scan body holds one
-    assert len(sites) == apps, sites
+    assert n == 1 and len(sites) == apps, sites
     for loc in sites:
-        path = loc.split('"')[1]
-        assert path.split("/").count(scopes.ATTENTION) == 1, loc
-        assert "decode_attention" in path, loc
+        assert _scopes_on(loc) == [scopes.ATTENTION], loc
+        assert "decode_attention" in loc.split('"')[1], loc
+
+
+@pytest.mark.parametrize("arch", ["stablelm_3b", "zamba2_7b"])
+def test_kv_write_kernel_falls_under_the_kv_write_scope(arch):
+    """Lowered for a TPU, the decode step writes every layer's or
+    application's new rows with one call of the row-write kernel, inside the
+    ``kv_write`` scope and no other: the benchmark's split puts its device
+    time there."""
+    n, sites = _kernel_calls(reduced_config(arch))["write_rows"]
+    assert n == 1 and len(sites) == 1, sites
+    assert _scopes_on(sites[0]) == [scopes.KV_WRITE], sites

@@ -303,3 +303,22 @@ def test_server_counts_the_kv_blocks_each_decode_step_reads(telemetry, monkeypat
     counters = obs.metrics().snapshot()
     assert counters["serve.kv_blocks_read"] == read
     assert counters["serve.kv_blocks_stored"] == 2 * new * len(prompts)
+
+
+def test_server_counts_the_bytes_each_decode_step_writes(telemetry):
+    """Each ``serve.step`` holds the bytes of the tiles that the row-write
+    kernel rewrites, K and V together: every slot's window of 128 positions
+    in every layer and head, ``Dh`` padded to whole bfloat16 tiles; the
+    counter sums them over the steps, idle slots included."""
+    from repro import obs
+
+    cfg = reduced_config("stablelm_3b")
+    params, _ = Model(cfg).init(jax.random.PRNGKey(0))
+    scfg = ServeConfig(batch_slots=2, max_len=256, max_new_tokens=4, eos=-1)
+    reqs = [Request(uid=i, prompt=np.ones((n,), np.int32)) for i, n in enumerate([3, 130, 9])]
+    Server(cfg, scfg, params).serve(reqs)
+
+    per_step = 2 * cfg.n_layers * scfg.batch_slots * cfg.n_kv_heads * 16 * 128 * 2
+    steps = [e for e in telemetry.events if e.name == "serve.step"]
+    assert steps and all(e.attrs["kv_write_bytes"] == per_step for e in steps)
+    assert obs.metrics().snapshot()["serve.kv_write_bytes"] == per_step * len(steps)
